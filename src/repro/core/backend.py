@@ -1,7 +1,7 @@
 """Measurement backends: the oracle that labels (config, inputs) -> TFLOPS.
 
-The paper benchmarks 50k real kernels on a GPU (§4).  This container has no
-TPU attached, so the backend is pluggable (DESIGN.md §2):
+The paper benchmarks 50k real kernels on a GPU (§4).  The backend is
+pluggable (DESIGN.md §2), so tuning runs with or without a TPU attached:
 
   * :class:`SimulatedTPUBackend` — analytical TPU v5e model with exactly the
     max(latency/n, throughput) saturation structure the paper cites from
@@ -9,11 +9,10 @@ TPU attached, so the backend is pluggable (DESIGN.md §2):
     instead of warp occupancy, VMEM instead of shared memory, MXU alignment
     instead of warp shapes).  Deterministic given (config, inputs, seed), with
     multiplicative log-normal noise mimicking measurement jitter.
-  * :class:`WallClockBackend` — times real jax.jit executions on the attached
-    devices (XLA:CPU here; XLA:TPU on a real pod).  Demonstrates the pipeline
-    end-to-end against true measurements.
-  * :class:`InterpretBackend` — executes the actual Pallas kernel under
-    interpret=True and checks it against the jnp reference; returns the
+  * :class:`WallClockBackend` — times the compiled Pallas GEMM with the
+    config under test on the attached TPU; raises without one.
+  * :class:`InterpretBackend` — executes the actual Pallas kernel
+    (interpreted off a TPU) and checks it against the jnp reference; returns the
     simulator's throughput on success, raises on numerical mismatch.  Used by
     tests to guarantee every sampled config is *runnable*, the property that
     separates X from X-hat.
@@ -331,12 +330,13 @@ class SimulatedTPUBackend:
 
 @dataclasses.dataclass
 class WallClockBackend:
-    """Times real jitted executions on the attached devices.
+    """Times the compiled Pallas GEMM, with the config under test, on the
+    attached TPU.
 
-    On this container that is XLA:CPU — useful to prove the end-to-end tuning
-    loop runs against real measurements (the space that matters on CPU is
-    k_split/precision, not VMEM tiling).  On a real TPU pod the same class
-    times the Pallas kernels themselves.
+    Every tuning parameter reaches the kernel (``ops.matmul(...,
+    interpret=False)``), so the label is that config's own time.  Without a
+    TPU it raises: a host clock around XLA:CPU or the Pallas interpreter
+    is no measurement of the kernel.
     """
 
     warmup: int = 1
@@ -347,41 +347,35 @@ class WallClockBackend:
         import jax
         import jax.numpy as jnp
 
+        from repro.kernels import ops
+
+        if jax.default_backend() != "tpu":
+            raise RuntimeError(
+                "WallClockBackend times compiled kernels on a TPU; JAX's "
+                f"backend here is {jax.default_backend()!r}")
         if space_name != "gemm":
             raise NotImplementedError("WallClockBackend covers GEMM")
         M, N, K = inputs["M"], inputs["N"], inputs["K"]
         dtype = jnp.bfloat16 if inputs["dtype_bits"] <= 16 else jnp.float32
-        ks = cfg.get("k_split", 1)
-        key = jax.random.PRNGKey(0)
-        a = jax.random.normal(key, (M, K), jnp.float32).astype(dtype)
-        b = jax.random.normal(key, (K, N), jnp.float32).astype(dtype)
-
-        if ks > 1 and K % ks == 0:
-            def f(a, b):
-                ar = a.reshape(M, ks, K // ks).swapaxes(0, 1)
-                br = b.reshape(ks, K // ks, N)
-                part = jnp.einsum("smk,skn->smn", ar, br,
-                                  preferred_element_type=jnp.float32)
-                return part.sum(0).astype(dtype)
-        else:
-            def f(a, b):
-                return jnp.matmul(a, b, preferred_element_type=jnp.float32
-                                  ).astype(dtype)
-        jf = jax.jit(f)
-        out = jf(a, b)
-        out.block_until_ready()
-        for _ in range(self.warmup):
+        ka, kb = jax.random.split(jax.random.PRNGKey(0))
+        a = jax.random.normal(ka, (M, K), jnp.float32).astype(dtype)
+        b = jax.random.normal(kb, (K, N), jnp.float32).astype(dtype)
+        cfg = dict(cfg)
+        jf = jax.jit(lambda a, b: ops.matmul(a, b, cfg, interpret=False))
+        for _ in range(1 + self.warmup):     # the first call compiles
             jf(a, b).block_until_ready()
         t0 = time.perf_counter()
         for _ in range(self.iters):
-            jf(a, b).block_until_ready()
+            out = jf(a, b)
+        out.block_until_ready()
         dt = (time.perf_counter() - t0) / self.iters
         return 2.0 * M * N * K / dt / 1e12
 
 
 @dataclasses.dataclass
 class InterpretBackend:
-    """Correctness oracle: run the real Pallas kernel (interpret=True) vs ref.
+    """Correctness oracle: run the real Pallas kernel (interpreted off a TPU)
+    vs ref.
 
     Throughput cannot be measured in interpret mode; on success returns the
     simulator's estimate so the tuning loop composes, on numerical mismatch

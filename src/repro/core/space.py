@@ -23,8 +23,13 @@ from typing import Callable, Dict, Iterable, List, Mapping, Tuple
 # ---------------------------------------------------------------------------
 # Hardware constants for legality checks (TPU v5e target; see DESIGN.md §2).
 # ---------------------------------------------------------------------------
-VMEM_BYTES = 128 * 1024 * 1024          # v5e VMEM per TensorCore
-VMEM_USABLE = int(VMEM_BYTES * 0.75)    # leave headroom for spills/semaphores
+# Scoped VMEM one kernel may use: every pallas_call is compiled with this as
+# its vmem_limit_bytes (kernels/common.py), and a config is legal only if
+# its *_vmem_bytes count fits in it.  v5e has 128 MiB of VMEM per core.
+VMEM_LIMIT_BYTES = 96 * 1024 * 1024
+# Mosaic's own scratch inside that limit (80 KiB measured for the largest
+# GEMM block), charged to every count with margin
+MOSAIC_SCRATCH_BYTES = 512 * 1024
 SUBLANE = 8                             # fp32 sublane tile
 LANE = 128                              # lane tile
 MXU = 128                               # systolic array dimension
@@ -106,15 +111,25 @@ def _round_up(a: int, b: int) -> int:
     return _ceil_div(a, b) * b
 
 
+def _tile_bytes(rows: int, cols: int, bpe: int) -> int:
+    """VMEM bytes of a (rows, cols) array: rows pad to the dtype's sublane
+    tile (8 for 32-bit, 16 for 16-bit), cols to the 128-lane tile."""
+    return _round_up(rows, SUBLANE * max(4 // bpe, 1)) * _round_up(cols, LANE) \
+        * bpe
+
+
 def gemm_vmem_bytes(cfg: Mapping[str, int], dtype_bits: int) -> int:
-    """VMEM working set of the Pallas GEMM for a configuration."""
+    """Scoped VMEM of the Pallas GEMM for a configuration.
+
+    Pallas double-buffers every input and output block whatever
+    ``prefetch`` says; the kernel adds its accumulator scratch and the f32
+    result of each block dot."""
     bpe = dtype_bits // 8
-    nbuf = 2 if cfg["prefetch"] >= 2 else 1      # double-buffered input slabs
-    a_slab = cfg["bm"] * cfg["bk"] * bpe
-    b_slab = cfg["bk"] * cfg["bn"] * bpe
-    acc_bpe = 4 if cfg["acc32"] else bpe
-    out = cfg["bm"] * cfg["bn"] * acc_bpe
-    return nbuf * (a_slab + b_slab) + out
+    bm, bn, bk = cfg["bm"], cfg["bn"], cfg["bk"]
+    blocks = _tile_bytes(bm, bk, bpe) + _tile_bytes(bk, bn, bpe) \
+        + _tile_bytes(bm, bn, bpe)
+    acc = _tile_bytes(bm, bn, 4 if cfg["acc32"] else bpe)
+    return 2 * blocks + acc + _tile_bytes(bm, bn, 4) + MOSAIC_SCRATCH_BYTES
 
 
 def gemm_is_legal(cfg: Mapping[str, int], inputs: Mapping[str, int]) -> bool:
@@ -124,7 +139,7 @@ def gemm_is_legal(cfg: Mapping[str, int], inputs: Mapping[str, int]) -> bool:
     bits = inputs["dtype_bits"]
     bm, bn, bk = cfg["bm"], cfg["bn"], cfg["bk"]
     # -- resource limits ----------------------------------------------------
-    if gemm_vmem_bytes(cfg, bits) > VMEM_USABLE:
+    if gemm_vmem_bytes(cfg, bits) > VMEM_LIMIT_BYTES:
         return False
     # -- alignment: lane/sublane tiles must be respected by the block shape --
     if bm % SUBLANE or bn % LANE:
@@ -187,15 +202,46 @@ def conv_out_shape(inputs: Mapping[str, int]) -> Tuple[int, int]:
     return inputs["H"], inputs["W"]
 
 
-def conv_vmem_bytes(cfg: Mapping[str, int], dtype_bits: int) -> int:
-    bpe = dtype_bits // 8
-    nbuf = 2 if cfg["prefetch"] >= 2 else 1
-    # I slab: b_npq spatial elements x b_c channels, F slab: b_c*rs x b_k.
-    i_slab = cfg["b_npq"] * cfg["b_c"] * bpe * cfg["rs_unroll"]
-    f_slab = cfg["b_c"] * cfg["rs_unroll"] * cfg["b_k"] * bpe
-    acc_bpe = 4 if cfg["acc32"] else bpe
-    out = cfg["b_npq"] * cfg["b_k"] * acc_bpe
-    return nbuf * (i_slab + f_slab) + out
+CONV_W_TILE = 16        # output width padding: the bf16 sublane tile
+
+
+def conv_blocks(cfg: Mapping[str, int], inputs: Mapping[str, int]
+                ) -> Dict[str, int]:
+    """The blocks ``ops.conv2d`` runs a config with: output rows per block
+    ``b_p``, the tile-padded output width ``Q``, and the channel/filter
+    blocks after fitting them to C and K (one channel block spanning the
+    whole channel dim when ``b_c >= C``)."""
+    P = inputs["H"]
+    Q = _round_up(inputs["W"], CONV_W_TILE)
+    C, K = inputs["C"], inputs["K"]
+    b_k, b_c, cs = cfg["b_k"], cfg["b_c"], cfg["c_split"]
+    while b_k > K and b_k > LANE:
+        b_k //= 2
+    if b_c >= C:
+        b_c, cs = C, 1
+    while cs > 1 and b_c * cs > C:
+        cs //= 2
+    b_p = max(min(cfg["b_npq"] // Q, P), 1)
+    while P % b_p:
+        b_p -= 1
+    return {"b_p": b_p, "Q": Q, "b_c": b_c, "c_split": cs, "b_k": b_k}
+
+
+def conv_vmem_bytes(cfg: Mapping[str, int], inputs: Mapping[str, int]) -> int:
+    """Scoped VMEM of the Pallas conv: the double-buffered padded image
+    slab, filter and output blocks, the accumulator scratch, and the
+    window, dot result and running sum the kernel body holds."""
+    bpe = inputs["dtype_bits"] // 8
+    R, S = inputs["R"], inputs["S"]
+    blk = conv_blocks(cfg, inputs)
+    Q, b_c, b_k = blk["Q"], blk["b_c"], blk["b_k"]
+    rows = blk["b_p"] * Q
+    image = (inputs["H"] + R - 1) * _tile_bytes(Q + S - 1, b_c, bpe)
+    filt = R * S * _tile_bytes(b_c, b_k, bpe)
+    out = _tile_bytes(rows, b_k, bpe)
+    acc = _tile_bytes(rows, b_k, 4 if cfg["acc32"] else bpe)
+    body = _tile_bytes(rows, b_c, bpe) + 2 * _tile_bytes(rows, b_k, 4)
+    return 2 * (image + filt + out) + acc + body + MOSAIC_SCRATCH_BYTES
 
 
 def conv_is_legal(cfg: Mapping[str, int], inputs: Mapping[str, int]) -> bool:
@@ -203,9 +249,12 @@ def conv_is_legal(cfg: Mapping[str, int], inputs: Mapping[str, int]) -> bool:
     P, Q = conv_out_shape(inputs)
     npq = inputs["N"] * P * Q
     C, K, R, S = inputs["C"], inputs["K"], inputs["R"], inputs["S"]
-    if conv_vmem_bytes(cfg, bits) > VMEM_USABLE:
+    if conv_vmem_bytes(cfg, inputs) > VMEM_LIMIT_BYTES:
         return False
     if cfg["b_npq"] % SUBLANE or cfg["b_k"] % LANE:
+        return False
+    # a channel block narrower than C is the slab's lane dim: whole lanes
+    if cfg["b_c"] < C and cfg["b_c"] % LANE:
         return False
     c_steps = _ceil_div(C, cfg["b_c"])
     if cfg["c_split"] > c_steps:
@@ -244,16 +293,24 @@ ATTENTION_PARAMS: Dict[str, Tuple[int, ...]] = {
 ATTENTION_INPUTS = ("B", "Hq", "Hkv", "Lq", "Lkv", "D", "dtype_bits", "causal")
 
 
+def attention_vmem_bytes(cfg: Mapping[str, int],
+                         inputs: Mapping[str, int]) -> int:
+    """Scoped VMEM of the Pallas flash attention: double-buffered q, k, v
+    and output blocks, the running max/denominator/accumulator scratch, and
+    the score-sized temporaries of the online softmax."""
+    bpe = inputs["dtype_bits"] // 8
+    d = inputs["D"]
+    b_q = min(cfg["b_q"], max(inputs["Lq"], 1))
+    b_kv = min(cfg["b_kv"], max(inputs["Lkv"], 1))
+    blocks = 2 * _tile_bytes(b_q, d, bpe) + 2 * _tile_bytes(b_kv, d, bpe)
+    scratch = 2 * _tile_bytes(b_q, 1, 4) + _tile_bytes(b_q, d, 4)
+    scores = 4 * _tile_bytes(b_q, b_kv, 4)
+    return 2 * blocks + scratch + scores + MOSAIC_SCRATCH_BYTES
+
+
 def attention_is_legal(cfg: Mapping[str, int], inputs: Mapping[str, int]) -> bool:
     bits = inputs["dtype_bits"]
-    bpe = bits // 8
-    d = inputs["D"]
-    nbuf = 2 if cfg["prefetch"] >= 2 else 1
-    q = cfg["b_q"] * d * bpe
-    kv = 2 * cfg["b_kv"] * d * bpe * nbuf
-    scores = cfg["b_q"] * cfg["b_kv"] * 4
-    acc = cfg["b_q"] * d * 4 + 2 * cfg["b_q"] * 4
-    if q + kv + scores + acc > VMEM_USABLE:
+    if attention_vmem_bytes(cfg, inputs) > VMEM_LIMIT_BYTES:
         return False
     if bits == 32 and not cfg["acc32"]:
         return False
@@ -281,19 +338,28 @@ SSD_PARAMS: Dict[str, Tuple[int, ...]] = {
 SSD_INPUTS = ("B", "L", "H", "P", "S", "dtype_bits")   # P=head dim, S=state dim
 
 
+def ssd_vmem_bytes(cfg: Mapping[str, int], inputs: Mapping[str, int]) -> int:
+    """Scoped VMEM of the Pallas SSD scan: double-buffered x, dt, B, C and
+    y blocks, the carried f32 state, and the (chunk, chunk) f32
+    temporaries of the intra-chunk quadratic form (the unrolled head loop
+    keeps some of them live per head)."""
+    bpe = inputs["dtype_bits"] // 8
+    p, s = inputs["P"], inputs["S"]
+    c = min(cfg["chunk"], inputs["L"])
+    bh = min(cfg["b_heads"], inputs["H"])
+    blocks = bh * (2 * _tile_bytes(c, p, bpe) + _tile_bytes(c, 1, bpe)) \
+        + 2 * _tile_bytes(c, s, bpe)
+    state = bh * _tile_bytes(p, s, 4)
+    body = (8 + 2 * bh) * _tile_bytes(c, c, 4) \
+        + 4 * bh * _tile_bytes(c, max(p, s), 4)
+    return 2 * blocks + state + body + MOSAIC_SCRATCH_BYTES
+
+
 def ssd_is_legal(cfg: Mapping[str, int], inputs: Mapping[str, int]) -> bool:
     bits = inputs["dtype_bits"]
-    bpe = bits // 8
-    c, bh = cfg["chunk"], cfg["b_heads"]
-    p, s = inputs["P"], inputs["S"]
-    nbuf = 2 if cfg["prefetch"] >= 2 else 1
-    x = bh * c * p * bpe * nbuf
-    bc = 2 * bh * c * s * bpe * nbuf
-    state = bh * p * s * 4
-    intra = bh * c * c * 4
-    if x + bc + state + intra + bh * c * p * 4 > VMEM_USABLE:
+    if ssd_vmem_bytes(cfg, inputs) > VMEM_LIMIT_BYTES:
         return False
-    if c > _round_up(inputs["L"], LANE):
+    if cfg["chunk"] > _round_up(inputs["L"], LANE):
         return False
     if bits == 32 and not cfg["acc32"]:
         return False

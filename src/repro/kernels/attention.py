@@ -16,12 +16,14 @@ ops.flash_attention pads Lq/Lkv and handles the causal offset for decode
 from __future__ import annotations
 
 import functools
-from typing import Mapping
+from typing import Mapping, Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .common import COMPILER_PARAMS, interpret_mode
 
 NEG_INF = -1e30
 
@@ -71,7 +73,7 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
                            cfg: Mapping[str, int], *, causal: bool = True,
                            q_offset: int = 0,
-                           interpret: bool = True) -> jax.Array:
+                           interpret: Optional[bool] = None) -> jax.Array:
     """Aligned flash attention.  Lq % b_q == 0, Lkv % b_kv == 0 required."""
     B, Hq, Lq, D = q.shape
     _, Hkv, Lkv, _ = k.shape
@@ -108,5 +110,6 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((b_q, 1), jnp.float32),      # running denominator
             pltpu.VMEM((b_q, D), jnp.float32),      # output accumulator
         ],
-        interpret=interpret,
+        compiler_params=COMPILER_PARAMS,
+        interpret=interpret_mode(interpret),
     )(q, k, v)

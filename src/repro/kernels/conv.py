@@ -21,18 +21,22 @@ Tuning parameters (core/space.py):
 
 Layouts: I (N, H, W, C), F (R, S, C, K), O (N, P, Q, K); SAME padding,
 stride 1 (the DeepBench regime the paper evaluates).  ops.conv2d pads
-spatially+channel-wise and slices the result.
+spatially+channel-wise and slices the result; it also pads the output width
+Q to the sublane tile, so each (b_p, Q, b_c) window flattens to a matmul
+operand without a relayout.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Mapping
+from typing import Mapping, Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .common import COMPILER_PARAMS, interpret_mode
 
 
 def _conv_kernel(i_ref, f_ref, o_ref, acc_ref, *, c_steps: int, b_p: int,
@@ -45,18 +49,16 @@ def _conv_kernel(i_ref, f_ref, o_ref, acc_ref, *, c_steps: int, b_p: int,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    img = i_ref[0]                      # (Hp, Wp, b_c) padded slab in VMEM
+    b_c = i_ref.shape[-1]               # i_ref: (1, Hp, Wp, b_c) slab in VMEM
     acc = acc_ref[...]                  # (b_p * Q, b_k)
     row0 = p * b_p
     for r in range(R):                  # fully-unrolled shifted-window walk
         for s in range(S):
-            win = jax.lax.dynamic_slice(
-                img, (row0 + r, s, 0),
-                (b_p, Q, img.shape[-1]))                 # (b_p, Q, b_c)
-            lhs = win.reshape(b_p * Q, img.shape[-1])
+            win = i_ref[0, pl.ds(row0 + r, b_p), pl.ds(s, Q), :]
+            lhs = win.reshape(b_p * Q, b_c)              # Q is tile-aligned
             rhs = f_ref[r, s]                            # (b_c, b_k)
-            acc = acc + jnp.dot(lhs, rhs,
-                                preferred_element_type=acc.dtype)
+            acc = acc + jnp.dot(lhs, rhs, preferred_element_type=jnp.float32
+                                ).astype(acc.dtype)     # as in matmul.py
     acc_ref[...] = acc
 
     @pl.when(c == c_steps - 1)
@@ -67,12 +69,14 @@ def _conv_kernel(i_ref, f_ref, o_ref, acc_ref, *, c_steps: int, b_p: int,
 
 
 def conv2d_pallas(i_pad: jax.Array, f: jax.Array, cfg: Mapping[str, int], *,
-                  P: int, Q: int, interpret: bool = True) -> jax.Array:
+                  P: int, Q: int, b_p: int,
+                  interpret: Optional[bool] = None) -> jax.Array:
     """Aligned conv on pre-padded input.
 
     i_pad: (N, P + R - 1, Q + S - 1, C) — spatially SAME-padded, P % b_p == 0,
            C % (c_split * b_c) == 0, channels padded.
     f:     (R, S, C, K), K % b_k == 0.
+    b_p:   output rows per block (ops.conv2d derives it from b_npq).
     Returns (c_split, N, P, Q, K) partial outputs.
     """
     N, Hp, Wp, C = i_pad.shape
@@ -81,9 +85,7 @@ def conv2d_pallas(i_pad: jax.Array, f: jax.Array, cfg: Mapping[str, int], *,
     b_k, b_c = cfg["b_k"], cfg["b_c"]
     cs = cfg.get("c_split", 1)
     acc32 = bool(cfg.get("acc32", 1))
-    b_p = max(cfg["b_npq"] // Q, 1)
-    if P % b_p:                        # ops guarantees this; double-check
-        b_p = 1
+    assert P % b_p == 0, (P, b_p)
     assert K % b_k == 0 and C % (cs * b_c) == 0, ((K, C), (b_k, b_c, cs))
     gp, gk = P // b_p, K // b_k
     cps = C // (cs * b_c)              # sequential C steps per split
@@ -108,5 +110,6 @@ def conv2d_pallas(i_pad: jax.Array, f: jax.Array, cfg: Mapping[str, int], *,
         out_specs=pl.BlockSpec((1, 1, b_p, Q, b_k), o_map),
         out_shape=jax.ShapeDtypeStruct((cs, N, P, Q, K), i_pad.dtype),
         scratch_shapes=[pltpu.VMEM((b_p * Q, b_k), acc_dtype)],
-        interpret=interpret,
+        compiler_params=COMPILER_PARAMS,
+        interpret=interpret_mode(interpret),
     )(i_pad, f)

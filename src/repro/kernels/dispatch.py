@@ -1,14 +1,15 @@
 """Kernel dispatch: route model compute through tuned kernels.
 
-On TPU, ``matmul``/``conv2d``/... run the Pallas kernels with the
+On TPU, ``matmul``/``conv2d``/... run the compiled Pallas kernels with the
 input-aware configuration from the installed tuner (the paper's §6 runtime:
 input parameters fixed by the call site, tuning parameters inferred and
 cached).  On CPU — including the multi-pod dry-run — they lower to plain XLA
-ops so ``cost_analysis()`` reflects the true dataflow (DESIGN.md §4).
+ops so ``cost_analysis()`` reflects the true dataflow (DESIGN.md §4), unless
+``prefer_kernel`` asks for the Pallas path, which then runs interpreted.
 
-``check_config`` executes a Pallas kernel under interpret mode against its
-ref.py oracle — the correctness notion of kernel legality used by
-InterpretBackend and the test suite.
+``check_config`` runs a Pallas kernel (compiled on a TPU, interpreted
+elsewhere) against its ref.py oracle — the correctness notion of kernel
+legality used by InterpretBackend and the test suite.
 """
 
 from __future__ import annotations
@@ -262,7 +263,7 @@ def matmul(a: jax.Array, b: jax.Array, *, prefer_kernel: bool = False
         _record("gemm", inputs)
         if on_tpu() or prefer_kernel:
             cfg = _tuned_cfg("gemm", inputs)
-            return ops.matmul(a, b, cfg, interpret=not on_tpu())
+            return ops.matmul(a, b, cfg)
     return jnp.dot(a, b)
 
 
@@ -293,7 +294,7 @@ def conv2d(i: jax.Array, f: jax.Array, *, prefer_kernel: bool = False
     _record("conv", inputs)
     if on_tpu() or prefer_kernel:
         cfg = _tuned_cfg("conv", inputs)
-        return ops.conv2d(i, f, cfg, interpret=not on_tpu())
+        return ops.conv2d(i, f, cfg)
     return ref.conv2d_ref(i, f)
 
 
@@ -306,8 +307,7 @@ def flash_attention(q, k, v, *, causal=True, q_offset=0,
     if on_tpu() or prefer_kernel:
         cfg = _tuned_cfg("attention", inputs)
         return ops.flash_attention(q, k, v, cfg, causal=causal,
-                                   q_offset=q_offset,
-                                   interpret=not on_tpu())
+                                   q_offset=q_offset)
     return ref.attention_ref(q, k, v, causal=causal, q_offset=q_offset)
 
 
@@ -318,7 +318,7 @@ def ssd_scan(x, dt, a, bm, cm, *, prefer_kernel: bool = False):
     _record("ssd", inputs)
     if on_tpu() or prefer_kernel:
         cfg = _tuned_cfg("ssd", inputs)
-        return ops.ssd_scan(x, dt, a, bm, cm, cfg, interpret=not on_tpu())
+        return ops.ssd_scan(x, dt, a, bm, cm, cfg)
     # CPU/dry-run path: chunked-but-pure-jnp SSD (identical math, XLA ops)
     return ref.ssd_ref(x, dt, a, bm, cm)
 
@@ -331,9 +331,10 @@ def check_config(space_name: str, cfg: Dict[str, int],
                  inputs: Dict[str, int], *, rtol: float = 2e-2,
                  seed: int = 0, max_dim: int = 512) -> None:
     """Run the Pallas kernel for `cfg` on a shrunken instance of `inputs`
-    (interpret mode) and assert allclose against the jnp oracle.  Raises on
-    mismatch.  Dims are capped at max_dim to keep interpret mode fast — the
-    config's *structure* (splits, unrolls, block shapes) is exercised fully.
+    (compiled on a TPU, interpreted elsewhere) and assert allclose against
+    the jnp oracle.  Raises on mismatch.  Dims are capped at max_dim to keep
+    interpret mode fast — the config's *structure* (splits, unrolls, block
+    shapes) is exercised fully.
     """
     rng = np.random.default_rng(seed)
     dtype = jnp.bfloat16 if inputs.get("dtype_bits", 16) <= 16 else jnp.float32

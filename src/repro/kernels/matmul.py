@@ -25,12 +25,14 @@ simulator charges that padding via its alignment-efficiency terms).
 from __future__ import annotations
 
 import functools
-from typing import Mapping
+from typing import Mapping, Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .common import COMPILER_PARAMS, interpret_mode
 
 
 def _gemm_kernel(a_ref, b_ref, o_ref, acc_ref, *, k_steps: int,
@@ -52,7 +54,10 @@ def _gemm_kernel(a_ref, b_ref, o_ref, acc_ref, *, k_steps: int,
     for u in range(k_unroll):
         a_u = jax.lax.slice_in_dim(a, u * sub, (u + 1) * sub, axis=1)
         b_u = jax.lax.slice_in_dim(b, u * sub, (u + 1) * sub, axis=0)
-        acc = acc + jnp.dot(a_u, b_u, preferred_element_type=acc_t)
+        # the MXU result is f32 (Mosaic refuses a narrower one); acc32=0
+        # rounds it into the IO-dtype accumulator every step
+        acc = acc + jnp.dot(a_u, b_u, preferred_element_type=jnp.float32
+                            ).astype(acc_t)
     acc_ref[...] = acc
 
     @pl.when(k == k_steps - 1)
@@ -61,7 +66,7 @@ def _gemm_kernel(a_ref, b_ref, o_ref, acc_ref, *, k_steps: int,
 
 
 def matmul_pallas(a: jax.Array, b: jax.Array, cfg: Mapping[str, int], *,
-                  interpret: bool = True) -> jax.Array:
+                  interpret: Optional[bool] = None) -> jax.Array:
     """Aligned GEMM: a (M, K) @ b (K, N) -> (k_split, M, N) partials.
 
     Requires M % bm == 0, N % bn == 0, K % (k_split * bk) == 0 (ops.matmul
@@ -108,5 +113,6 @@ def matmul_pallas(a: jax.Array, b: jax.Array, cfg: Mapping[str, int], *,
         out_specs=pl.BlockSpec((1, bm, bn), o_map),
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype)],
-        interpret=interpret,
+        compiler_params=COMPILER_PARAMS,
+        interpret=interpret_mode(interpret),
     )(a, b)

@@ -10,6 +10,8 @@ from typing import Mapping, Optional
 import jax
 import jax.numpy as jnp
 
+from repro.core.space import conv_blocks, conv_input
+
 from . import attention as _attention
 from . import conv as _conv
 from . import matmul as _matmul
@@ -35,7 +37,7 @@ def _pad_to(x: jax.Array, axis: int, mult: int) -> jax.Array:
 
 def matmul(a: jax.Array, b: jax.Array,
            cfg: Optional[Mapping[str, int]] = None, *,
-           interpret: bool = True) -> jax.Array:
+           interpret: Optional[bool] = None) -> jax.Array:
     """C = A @ B through the parameterized Pallas kernel (pads + reduces)."""
     cfg = {**DEFAULT_GEMM, **(cfg or {})}
     M, K = a.shape
@@ -64,43 +66,37 @@ def matmul(a: jax.Array, b: jax.Array,
 
 def conv2d(i: jax.Array, f: jax.Array,
            cfg: Optional[Mapping[str, int]] = None, *,
-           interpret: bool = True) -> jax.Array:
+           interpret: Optional[bool] = None) -> jax.Array:
     """SAME/stride-1 conv i (N,H,W,C) * f (R,S,C,K) -> (N,H,W,K)."""
     cfg = {**DEFAULT_CONV, **(cfg or {})}
     N, H, W, C = i.shape
     R, S, _, K = f.shape
-    P, Q = H, W
-    b_k, b_c, cs = cfg["b_k"], cfg["b_c"], cfg["c_split"]
-    while b_k > K and b_k > 128:
-        b_k //= 2
-    while b_c * cs > C and b_c > 32:
-        b_c //= 2
-    while cs > 1 and b_c * cs > max(C, b_c):
-        cs //= 2
-    b_p = max(min(cfg["b_npq"] // Q, P), 1)
-    while P % b_p:
-        b_p -= 1
+    # the output width is padded to the sublane tile (the extra columns see
+    # zero input and are sliced off), so a window flattens without relayout
+    blk = conv_blocks(cfg, conv_input(N, H, W, C, K, R, S))
+    P, Q, b_p = H, blk["Q"], blk["b_p"]
+    b_k, b_c, cs = blk["b_k"], blk["b_c"], blk["c_split"]
     cfg = {**cfg, "b_k": b_k, "b_c": b_c, "c_split": cs}
 
     # SAME padding (odd filters center; even filters follow XLA's convention)
     pt = (R - 1) // 2
     pb = R - 1 - pt
     pl_ = (S - 1) // 2
-    pr = S - 1 - pl_
+    pr = S - 1 - pl_ + Q - W
     i_pad = jnp.pad(i, ((0, 0), (pt, pb), (pl_, pr), (0, 0)))
     i_pad = _pad_to(i_pad, 3, b_c * cs)
     f_p = _pad_to(_pad_to(f, 2, b_c * cs), 3, b_k)
 
-    parts = _conv.conv2d_pallas(i_pad, f_p, cfg, P=P, Q=Q,
+    parts = _conv.conv2d_pallas(i_pad, f_p, cfg, P=P, Q=Q, b_p=b_p,
                                 interpret=interpret)
     out = parts.sum(axis=0) if cs > 1 else parts[0]
-    return out[:, :, :, :K]
+    return out[:, :, :W, :K]
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     cfg: Optional[Mapping[str, int]] = None, *,
                     causal: bool = True, q_offset: int = 0,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: Optional[bool] = None) -> jax.Array:
     """Padded flash attention; masks padded KV via the causal machinery."""
     cfg = {**DEFAULT_ATTN, **(cfg or {})}
     B, Hq, Lq, D = q.shape
@@ -123,7 +119,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
 def ssd_scan(x: jax.Array, dt: jax.Array, a: jax.Array, bm: jax.Array,
              cm: jax.Array, cfg: Optional[Mapping[str, int]] = None, *,
-             interpret: bool = True) -> jax.Array:
+             interpret: Optional[bool] = None) -> jax.Array:
     """Padded SSD chunk scan (pads L; padded steps have dt=0 => identity)."""
     cfg = {**DEFAULT_SSD, **(cfg or {})}
     B, L, H, P = x.shape
@@ -131,11 +127,11 @@ def ssd_scan(x: jax.Array, dt: jax.Array, a: jax.Array, bm: jax.Array,
     bh = cfg.get("b_heads", 1)
     while H % bh:
         bh //= 2
-    x_p = _pad_to(x, 1, chunk)
-    dt_p = _pad_to(dt, 1, chunk)
+    x_p = _pad_to(x, 1, chunk).transpose(0, 2, 1, 3)          # (B, H, L, P)
+    dt_p = _pad_to(dt, 1, chunk).transpose(0, 2, 1)[..., None]  # (B, H, L, 1)
     bm_p = _pad_to(bm, 1, chunk)
     cm_p = _pad_to(cm, 1, chunk)
-    out = _ssd.ssd_scan_pallas(x_p, dt_p, a, bm_p, cm_p,
+    out = _ssd.ssd_scan_pallas(x_p, dt_p, a.astype(jnp.float32), bm_p, cm_p,
                                {**cfg, "chunk": chunk, "b_heads": bh},
                                interpret=interpret)
-    return out[:, :L]
+    return out.transpose(0, 2, 1, 3)[:, :L]

@@ -10,95 +10,111 @@ inter-block communication.
 
 Tunables (core/space.py SSD_SPACE): chunk, b_heads, acc32, prefetch.
 
-Layouts: x (B, L, H, P), dt (B, L, H), A (H,), Bm/Cm (B, L, S) [ngroups=1],
-y (B, L, H, P).  ops.ssd_scan pads L to a chunk multiple.
+Layouts (head-major, so every block's last two dims are (chunk, P),
+(chunk, 1) or (chunk, S) and tile onto the TPU's (8, 128) grid):
+x (B, H, L, P), dt (B, H, L, 1), A (H,) in SMEM, Bm/Cm (B, L, S)
+[ngroups=1], y (B, H, L, P).  ops.ssd_scan transposes from the model's
+(B, L, H, P) layout and pads L to a chunk multiple.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Mapping
+from typing import Mapping, Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .common import COMPILER_PARAMS, interpret_mode
 
-def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_ref, *,
-                chunk: int):
+_HI = jax.lax.Precision.HIGHEST     # decay exponents must not round to bf16
+_NT = (((1,), (1,)), ((), ()))      # a @ b.T
+_TN = (((0,), (0,)), ((), ()))      # a.T @ b
+
+
+def _ssd_kernel(a_ref, x_ref, dt_ref, b_ref, c_ref, y_ref, state_ref, *,
+                b_heads: int):
+    hb = pl.program_id(1)
     ci = pl.program_id(2)
 
     @pl.when(ci == 0)
     def _init():
         state_ref[...] = jnp.zeros_like(state_ref)
 
-    x = x_ref[0].astype(jnp.float32)         # (chunk, bh, P)
-    dt = dt_ref[0].astype(jnp.float32)       # (chunk, bh)
-    a = a_ref[...].astype(jnp.float32)       # (bh,)
-    bm = b_ref[0].astype(jnp.float32)        # (chunk, S)
-    cm = c_ref[0].astype(jnp.float32)        # (chunk, S)
-
-    adt = dt * a[None, :]                    # (chunk, bh) log-decay per step
-    cum = jnp.cumsum(adt, axis=0)            # (chunk, bh)
-
-    # -- intra-chunk: masked quadratic form (the 'duality' matmul) ---------
-    # scores[i, j, h] = (C_i . B_j) * exp(cum[i,h] - cum[j,h]) for j <= i
-    cb = jnp.dot(cm, bm.T, preferred_element_type=jnp.float32)  # (c, c)
-    decay = jnp.exp(cum[:, None, :] - cum[None, :, :])          # (c, c, bh)
+    f32 = jnp.float32
+    bm = b_ref[0].astype(f32)                # (chunk, S)
+    cm = c_ref[0].astype(f32)                # (chunk, S)
+    chunk = bm.shape[0]
     ii = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    mask = (jj <= ii)[:, :, None]
-    scores = jnp.where(mask, cb[:, :, None] * decay, 0.0)       # (c, c, bh)
-    xdt = x * dt[:, :, None]                                    # (c, bh, P)
-    y_intra = jnp.einsum("ijh,jhp->ihp", scores, xdt)
+    causal = jj <= ii
+    # C_i . B_j, shared by every head (ngroups=1)
+    cb = jax.lax.dot_general(cm, bm, _NT, precision=_HI,
+                             preferred_element_type=f32)
+    for h in range(b_heads):
+        a = a_ref[hb * b_heads + h]
+        x = x_ref[0, h].astype(f32)          # (chunk, P)
+        dt = dt_ref[0, h].astype(f32)        # (chunk, 1)
+        adt = dt * a                         # per-step log-decay
+        # seg[i, j] = sum_{j < k <= i} adt[k]: one matmul against the
+        # row-scaled strictly-lower mask (no cumsum primitive needed)
+        seg = jnp.dot(causal.astype(f32), jnp.where(jj < ii, adt, 0.0),
+                      precision=_HI, preferred_element_type=f32)
+        cum = seg[:, :1] + adt[:1, :]        # (chunk, 1) inclusive cumsum
 
-    # -- inter-chunk: contribution of the carried state --------------------
-    state = state_ref[...]                                      # (bh, P, S)
-    y_inter = jnp.einsum("is,hps,ih->ihp", cm, state, jnp.exp(cum))
+        # -- intra-chunk: masked quadratic form (the 'duality' matmul) -----
+        scores = jnp.where(causal, cb * jnp.exp(seg), 0.0)
+        y = jnp.dot(scores, x * dt, precision=_HI,
+                    preferred_element_type=f32)
+        # -- inter-chunk: contribution of the carried state ----------------
+        state = state_ref[h]                 # (P, S)
+        y = y + jnp.exp(cum) * jax.lax.dot_general(
+            cm, state, _NT, precision=_HI, preferred_element_type=f32)
+        y_ref[0, h] = y.astype(y_ref.dtype)
 
-    y_ref[0] = (y_intra + y_inter).astype(y_ref.dtype)
-
-    # -- state update -------------------------------------------------------
-    tail = jnp.exp(cum[-1][None, :] - cum)                      # (c, bh)
-    contrib = jnp.einsum("jh,jhp,js->hps", tail * dt, x, bm)
-    state_ref[...] = state * jnp.exp(cum[-1])[:, None, None] + contrib
+        # -- state update ---------------------------------------------------
+        # Mosaic broadcasts one axis at a time, so every decay stays a row
+        # or a column: seg's last row is tot - cum[j], the tail decay of j
+        tail = jnp.where(ii == jj, jnp.exp(seg[chunk - 1:, :]), 0.0)
+        b_tail = jnp.dot(tail, bm, precision=_HI, preferred_element_type=f32)
+        contrib = jax.lax.dot_general(x * dt, b_tail, _TN, precision=_HI,
+                                      preferred_element_type=f32)
+        tot = jnp.sum(jnp.broadcast_to(adt, bm.shape), axis=0, keepdims=True)
+        state_ref[h] = state * jnp.exp(tot) + contrib       # tot: (1, S)
 
 
 def ssd_scan_pallas(x: jax.Array, dt: jax.Array, a: jax.Array,
                     bm: jax.Array, cm: jax.Array, cfg: Mapping[str, int], *,
-                    interpret: bool = True) -> jax.Array:
-    """Aligned SSD scan: L % chunk == 0, H % b_heads == 0 required."""
-    B, L, H, P = x.shape
+                    interpret: Optional[bool] = None) -> jax.Array:
+    """Aligned SSD scan on head-major operands: x (B, H, L, P),
+    dt (B, H, L, 1), a (H,) f32, bm/cm (B, L, S) -> y (B, H, L, P).
+    L % chunk == 0 and H % b_heads == 0 required."""
+    B, H, L, P = x.shape
     S = bm.shape[-1]
     chunk = min(cfg["chunk"], L)
     bh = min(cfg.get("b_heads", 1), H)
     assert L % chunk == 0 and H % bh == 0, ((L, H), (chunk, bh))
-    n_chunks = L // chunk
-    gh = H // bh
+    grid = (B, H // bh, L // chunk)          # chunks innermost: sequential
 
-    grid = (B, gh, n_chunks)                 # chunks innermost: sequential
-
-    x_map = lambda b, h, c: (b, c, h, 0)
-    dt_map = lambda b, h, c: (b, c, h)
-    a_map = lambda b, h, c: (h,)
+    x_map = lambda b, h, c: (b, h, c, 0)
     bc_map = lambda b, h, c: (b, c, 0)
-    y_map = lambda b, h, c: (b, c, h, 0)
-
-    kernel = functools.partial(_ssd_kernel, chunk=chunk)
+    kernel = functools.partial(_ssd_kernel, b_heads=bh)
 
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, chunk, bh, P), x_map),
-            pl.BlockSpec((1, chunk, bh), dt_map),
-            pl.BlockSpec((bh,), a_map),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, bh, chunk, P), x_map),
+            pl.BlockSpec((1, bh, chunk, 1), x_map),
             pl.BlockSpec((1, chunk, S), bc_map),
             pl.BlockSpec((1, chunk, S), bc_map),
         ],
-        out_specs=pl.BlockSpec((1, chunk, bh, P), y_map),
+        out_specs=pl.BlockSpec((1, bh, chunk, P), x_map),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         scratch_shapes=[pltpu.VMEM((bh, P, S), jnp.float32)],
-        interpret=interpret,
-    )(x, dt, a, bm, cm)
+        compiler_params=COMPILER_PARAMS,
+        interpret=interpret_mode(interpret),
+    )(a, x, dt, bm, cm)

@@ -1,8 +1,10 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 # ^^ MUST precede any jax import: jax locks the device count on first init.
 # This module is the ONLY place the 512 placeholder devices exist; tests and
-# benchmarks see the real single CPU device.
+# benchmarks see the real single CPU device.  The dry-run compiles for the
+# virtual CPU mesh, so on a TPU host it must not open (and hold) the chip.
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 

@@ -16,19 +16,18 @@ gradient all-reduce, int8-compressible); 'data' = FSDP batch+param shards;
 from __future__ import annotations
 
 import jax
-
-from repro.compat import auto_axis_types, make_mesh
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes, axis_types=auto_axis_types(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(model: int = 1) -> jax.sharding.Mesh:
     """Whatever-devices-exist mesh for tests/examples (1 CPU here)."""
     n = len(jax.devices())
     data = n // model
-    return make_mesh((data, model), ("data", "model"),
-                     axis_types=auto_axis_types(2))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)

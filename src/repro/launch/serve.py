@@ -7,6 +7,7 @@ import jax
 import numpy as np
 
 from repro.configs import ARCH_NAMES, get_config, smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params
 from repro.serve import Engine, ServeConfig
 
@@ -99,19 +100,21 @@ def main() -> None:
                         "/healthz answers 503 until the backlog drains")
     p.add_argument("--measure", choices=["wallclock", "sim"], default=None,
                    help="re-measure model top-k candidates on the serving "
-                        "path: 'wallclock' times real kernels on TPU "
-                        "(simulated fallback off-hardware, warns once), "
-                        "'sim' always uses the analytic backend")
+                        "path: 'wallclock' times the compiled kernels and "
+                        "needs a TPU, 'sim' uses the analytic backend")
     args = p.parse_args()
 
     cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
-    if jax.default_backend() == "cpu" and not args.smoke \
-            and cfg.param_count > 1e9:
-        raise SystemExit(f"{cfg.name} is dry-run-only here; use --smoke")
+    if jax.default_backend() != "tpu" and not args.smoke:
+        # a published config serves on the chip: without one, JAX would
+        # quietly run it on the CPU (the kernels interpreted)
+        raise SystemExit(f"{cfg.name} serves on a TPU and JAX found "
+                         f"{jax.default_backend()!r}; use --smoke here")
     if cfg.is_encdec:
         raise SystemExit("enc-dec serving is exercised via the dry-run "
                          "decode cells; the engine serves LM archs")
 
+    enable_compile_cache()
     params = init_params(cfg, jax.random.PRNGKey(0))
     eng = Engine(cfg, params, ServeConfig(
         max_len=args.max_len, slots=args.slots,

@@ -22,6 +22,7 @@ import jax
 
 from repro.configs import ARCH_NAMES, get_config, smoke_config
 from repro.data import DataConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.optim import AdamWConfig
 from repro.train import Trainer, TrainConfig
 
@@ -48,6 +49,7 @@ def main() -> None:
             f"{cfg.name} has {cfg.param_count/1e9:.0f}B params - on this "
             "host run with --smoke (full configs are dry-run only here)")
 
+    enable_compile_cache()
     trainer = Trainer(
         cfg,
         AdamWConfig(lr=args.lr, total_steps=args.steps,

@@ -33,8 +33,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
-
 from .layers import Params, dense_init
 
 
@@ -185,7 +183,7 @@ def moe_ep(p: Params, x: jax.Array, *, n_experts: int, top_k: int,
         # collapsed into one all-reduce (same bytes as a TP MLP psum)
         return jax.lax.psum(part, model_axis), aux
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(), P(model_axis), P(model_axis), P(model_axis),
                   P(batch_axes)),
@@ -250,7 +248,7 @@ def moe_ep_a2a(p: Params, x: jax.Array, *, n_experts: int, top_k: int,
         )(y, meta)
         return out, aux
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(), P(model_axis), P(model_axis), P(model_axis),
                   P(batch_axes, model_axis)),

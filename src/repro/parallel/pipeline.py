@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
 
 
 def pipeline_apply(stage_fn: Callable[[Any, jax.Array], jax.Array],
@@ -71,7 +70,7 @@ def pipeline_apply(stage_fn: Callable[[Any, jax.Array], jax.Array],
 
     spec_p = jax.tree_util.tree_map(
         lambda _: P(axis), stage_params)
-    fn = shard_map(per_device, mesh=mesh,
+    fn = jax.shard_map(per_device, mesh=mesh,
                    in_specs=(spec_p, P()), out_specs=P(),
                    check_vma=False)
     return fn(stage_params, x)

@@ -140,8 +140,8 @@ class ServeConfig:
     # Perfetto; see docs/OBSERVABILITY.md
     trace_sample: float = 0.0
     # §6 re-measurement backend for the model tier's top-k candidates:
-    # "wallclock" times real kernels (falls back to the simulator with a
-    # warn-once off TPU hardware), "sim" uses the analytic simulator, None
+    # "wallclock" times the compiled kernels and needs a TPU (the engine
+    # refuses to start without one), "sim" uses the analytic simulator, None
     # disables serving-path measurement.  Measurements are scheduled into
     # idle decode gaps (MeasureQueue), never inline on dispatch
     measure: Optional[str] = None
@@ -508,12 +508,12 @@ class Engine:
                 install_models(models if len(models) else None)
         # wall-clock measurer (paper §6 re-measurement, on the real clock):
         # the model tier's top-k candidates are re-measured by
-        # ServingMeasurer — wall clock on TPU hardware, simulator fallback
-        # (warn-once) off it — but never inline: predict() enqueues onto
-        # the MeasureQueue and the controller poll drains it in idle
-        # decode gaps (see maybe_retune).  One tiny calibration GEMM runs
-        # now, proving the backend path (and firing the off-hardware
-        # warning) before traffic arrives.
+        # ServingMeasurer — the compiled kernel's wall clock on a TPU, or
+        # the simulator when measure="sim" — but never inline: predict()
+        # enqueues onto the MeasureQueue and the controller poll drains it
+        # in idle decode gaps (see maybe_retune).  One tiny calibration
+        # GEMM runs now and proves the backend path before traffic
+        # arrives: if it fails, the engine does not start.
         self.measurer = None
         self._measure_queue = None
         if serve_cfg.measure:
@@ -526,14 +526,11 @@ class Engine:
             if live_models is not None:
                 live_models.measurer = self.measurer
                 live_models.measure_queue = self._measure_queue
-            try:
-                self.measurer("gemm",
-                              {"bm": 128, "bn": 128, "bk": 128,
-                               "k_unroll": 1, "k_split": 1, "order": 0,
-                               "acc32": 1, "prefetch": 2},
-                              gemm_input(256, 256, 256, 16))
-            except Exception:
-                pass            # a failed calibration must not stop serving
+            self.measurer("gemm",
+                          {"bm": 128, "bn": 128, "bk": 128,
+                           "k_unroll": 1, "k_split": 1, "order": 0,
+                           "acc32": 1, "prefetch": 2},
+                          gemm_input(256, 256, 256, 16))
         # startup dispatch probe: resolve each installed shape once through
         # the real dispatch path so the trace (and tier_latency) carries
         # tier attribution immediately — on TPU the decode compile would do

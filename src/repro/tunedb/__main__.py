@@ -412,6 +412,8 @@ def _spawn_workers(args: argparse.Namespace) -> List:
     restarted run never appends to a shard whose merge cursor already
     advanced.  PYTHONPATH is pinned to this process's ``repro`` checkout so
     the children resolve the same code regardless of the caller's env.
+    Workers tune on the simulator, so they run with ``JAX_PLATFORMS=cpu``:
+    on a TPU host none of them may open (and hold) the chip.
     """
     import pathlib
     import subprocess
@@ -423,6 +425,7 @@ def _spawn_workers(args: argparse.Namespace) -> List:
     src_root = str(pathlib.Path(list(repro.__path__)[0]).resolve().parent)
     env["PYTHONPATH"] = src_root + (
         os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
+    env["JAX_PLATFORMS"] = "cpu"
     cmd = [sys.executable, "-m", "repro.tunedb", "fleet", "worker",
            "--fleet", str(args.fleet),
            "--train-samples", str(args.worker_train_samples),

@@ -9,13 +9,14 @@ recipe in two:
 
 * :class:`ServingMeasurer` — the ``(space, cfg, inputs) -> TFLOPS``
   callable wired as ``ModelSet.measurer`` behind
-  ``ServeConfig(measure="wallclock")``.  On TPU it times the real kernels
-  via :class:`~repro.core.backend.WallClockBackend`; off-hardware (or for
-  a space wall-clock timing does not cover) it falls back to the analytic
-  :class:`~repro.core.backend.SimulatedTPUBackend` with ONE RuntimeWarning
-  — a dev box must run the same code path it ships.  Every measurement
-  increments ``tunedb_measurements_total{backend}`` and, when tracing is
-  on, records a ``measure.wallclock`` / ``measure.sim`` span — so the
+  ``ServeConfig(measure="wallclock")``.  ``"wallclock"`` times the
+  compiled kernels via :class:`~repro.core.backend.WallClockBackend` and
+  exists only on a TPU: it raises without one, and for a space wall-clock
+  timing does not cover, rather than hand back a simulated number under
+  its name.  ``"sim"`` uses the analytic
+  :class:`~repro.core.backend.SimulatedTPUBackend` anywhere.  Every
+  measurement increments ``tunedb_measurements_total{backend}`` and, when
+  tracing is on, records a ``measure.wallclock`` / ``measure.sim`` span — so the
   Perfetto view shows the tuner's measurements on the same clock as the
   decode ticks they stole time from.
 
@@ -32,7 +33,6 @@ recipe in two:
 from __future__ import annotations
 
 import threading
-import warnings
 from collections import deque
 from typing import Deque, Dict, List, Mapping, Optional, Tuple
 
@@ -55,8 +55,8 @@ def _count_measurement(backend: str) -> None:
 
 
 class ServingMeasurer:
-    """``ModelSet.measurer`` for a serving process: wall clock on
-    hardware, simulator off it, spans + counters either way."""
+    """``ModelSet.measurer`` for a serving process: the compiled kernel's
+    wall clock on a TPU, or the simulator, spans + counters either way."""
 
     def __init__(self, mode: str = "wallclock", *, warmup: int = 1,
                  iters: int = 3) -> None:
@@ -64,34 +64,22 @@ class ServingMeasurer:
             raise ValueError(f"measure mode {mode!r}; pick one of "
                              f"{MEASURE_MODES}")
         from repro.core.backend import SimulatedTPUBackend, WallClockBackend
+        if mode == "wallclock":
+            import jax
+            if jax.default_backend() != "tpu":
+                raise RuntimeError(
+                    "measure='wallclock' times compiled kernels on a TPU; "
+                    f"JAX's backend here is {jax.default_backend()!r} "
+                    "(use measure='sim' off the chip)")
+            self._backend = WallClockBackend(warmup=warmup, iters=iters)
+        else:
+            self._backend = SimulatedTPUBackend(noise=0.0)
         self.mode = mode
-        self._wall = (WallClockBackend(warmup=warmup, iters=iters)
-                      if mode == "wallclock" else None)
-        self._sim = SimulatedTPUBackend(noise=0.0)
         self.counts: Dict[str, int] = {"wallclock": 0, "sim": 0}
-        self._warned_fallback = False
-
-    def _on_hardware(self) -> bool:
-        import jax
-        return jax.default_backend() == "tpu"
-
-    def _pick_backend(self, space: str):
-        """(backend object, label) for one measurement."""
-        if self._wall is None:
-            return self._sim, "sim"
-        if not self._on_hardware():
-            if not self._warned_fallback:
-                self._warned_fallback = True
-                warnings.warn(
-                    "measure=wallclock without TPU hardware; re-measuring "
-                    "on the simulated backend instead",
-                    RuntimeWarning, stacklevel=3)
-            return self._sim, "sim"
-        return self._wall, "wallclock"
 
     def __call__(self, space: str, cfg: Mapping[str, int],
                  inputs: Mapping[str, int]) -> float:
-        backend, label = self._pick_backend(space)
+        label = self.mode
         from .obs import trace as _trace
         tr = _trace._TRACER
         ctx = None
@@ -107,32 +95,25 @@ class ServingMeasurer:
                               space=space, shape=shape)
         if ctx is not None:
             with ctx as sp:
-                tflops, label = self._measure(backend, label, space, cfg,
-                                              inputs)
+                tflops = self._measure(space, cfg, inputs)
                 if sp is not None:
                     sp.attrs["backend"] = label
                     sp.attrs["tflops"] = round(float(tflops), 3)
         else:
-            tflops, _ = self._measure(backend, label, space, cfg, inputs)
+            tflops = self._measure(space, cfg, inputs)
         return tflops
 
-    def _measure(self, backend, label: str, space: str,
-                 cfg: Mapping[str, int],
-                 inputs: Mapping[str, int]) -> Tuple[float, str]:
-        try:
-            tflops = float(backend.measure(space, cfg, inputs))
-        except NotImplementedError:
-            # wall-clock timing does not cover this space (GEMM-only
-            # today): the simulator keeps the §6 loop closed for it
-            label = "sim"
-            tflops = float(self._sim.measure(space, cfg, inputs))
-        self.counts[label] = self.counts.get(label, 0) + 1
-        _count_measurement(label)
-        return tflops, label
+    def _measure(self, space: str, cfg: Mapping[str, int],
+                 inputs: Mapping[str, int]) -> float:
+        # wall clock covers GEMM only: another space raises
+        # NotImplementedError here, never a simulated number
+        tflops = float(self._backend.measure(space, cfg, inputs))
+        self.counts[self.mode] += 1
+        _count_measurement(self.mode)
+        return tflops
 
     def stats(self) -> Dict[str, object]:
-        return {"mode": self.mode, "counts": dict(self.counts),
-                "fallback_warned": self._warned_fallback}
+        return {"mode": self.mode, "counts": dict(self.counts)}
 
 
 class MeasureQueue:

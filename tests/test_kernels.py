@@ -1,10 +1,12 @@
 """Per-kernel correctness: Pallas (interpret=True) vs ref.py oracles,
 swept over shapes / dtypes / tuning configurations."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
+from jax.experimental import pallas as pl
 
 from repro.kernels import dispatch, ops, ref
 
@@ -92,3 +94,25 @@ def test_check_config_gate():
         {"bm": 64, "bn": 128, "bk": 128, "k_unroll": 1, "k_split": 2,
          "order": 0, "acc32": 1, "prefetch": 2},
         {"M": 128, "N": 128, "K": 512, "dtype_bits": 16})
+
+
+@pytest.mark.parametrize("backend,interpret", [("cpu", True), ("tpu", False)])
+def test_interpret_default_follows_backend(monkeypatch, backend, interpret):
+    """Every kernel entry point left at its default runs compiled on a TPU
+    and interpreted on any other backend."""
+    seen = []
+
+    def fake_pallas_call(kernel, *, out_shape, interpret, **kwargs):
+        seen.append(interpret)
+        return lambda *args: jnp.zeros(out_shape.shape, out_shape.dtype)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    monkeypatch.setattr(pl, "pallas_call", fake_pallas_call)
+    x = jnp.ones((16, 128), jnp.float32)
+    ops.matmul(x, x.T)
+    ops.conv2d(jnp.ones((1, 8, 8, 16)), jnp.ones((3, 3, 16, 128)))
+    q = jnp.ones((1, 2, 16, 32))
+    ops.flash_attention(q, q, q)
+    ops.ssd_scan(jnp.ones((1, 32, 2, 16)), jnp.ones((1, 32, 2)),
+                 -jnp.ones((2,)), jnp.ones((1, 32, 16)), jnp.ones((1, 32, 16)))
+    assert seen == [interpret] * 4

@@ -5,7 +5,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.compat import auto_axis_types, make_mesh
+from jax.sharding import AxisType
+
 from repro.models.moe import (init_moe, moe, moe_decode, moe_ep, _route,
                               _capacity)
 
@@ -37,8 +38,8 @@ def test_capacity_drops_reduce_output(layer):
 def test_moe_ep_single_device_mesh(layer):
     """shard_map EP path on a 1-device mesh must equal the reference path."""
     p, x = layer
-    mesh = make_mesh((1, 1), ("data", "model"),
-                     axis_types=auto_axis_types(2))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     out_ep, aux_ep = moe_ep(p, x, n_experts=4, top_k=2,
                             capacity_factor=8.0, mesh=mesh)
     out_ref, aux_ref = moe(p, x, n_experts=4, top_k=2, capacity_factor=8.0)
@@ -71,10 +72,10 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import sys; sys.path.insert(0, "src")
 import jax, jax.numpy as jnp, numpy as np
-from repro.compat import auto_axis_types, make_mesh
+from jax.sharding import AxisType
 from repro.models.moe import init_moe, moe, moe_ep, moe_ep_a2a
-mesh = make_mesh((1, 4), ("data", "model"),
-                 axis_types=auto_axis_types(2))
+mesh = jax.make_mesh((1, 4), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 p = init_moe(jax.random.PRNGKey(0), 32, 64, 8, jnp.float32)
 x = jax.random.normal(jax.random.PRNGKey(1), (2, 32, 32))
 ref, _ = moe(p, x, n_experts=8, top_k=2, capacity_factor=8.0)

@@ -11,10 +11,10 @@ CHILD = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp, numpy as np
-from repro.compat import auto_axis_types, make_mesh
+from jax.sharding import AxisType
 from repro.parallel.pipeline import pipeline_apply, stage_split
 
-mesh = make_mesh((4,), ("stage",), axis_types=auto_axis_types(1))
+mesh = jax.make_mesh((4,), ("stage",), axis_types=(AxisType.Auto,))
 n_layers, d = 8, 16
 ws = jax.random.normal(jax.random.PRNGKey(0), (n_layers, d, d)) * 0.2
 

@@ -4,7 +4,7 @@ import numpy as np
 from hypothesis import given, settings, strategies as st
 
 from repro.core.space import (CONV_SPACE, GEMM_SPACE, SPACES, gemm_input,
-                              conv_input, gemm_vmem_bytes, VMEM_USABLE)
+                              conv_input, gemm_vmem_bytes, VMEM_LIMIT_BYTES)
 
 
 def test_cardinality():
@@ -25,7 +25,7 @@ def test_legal_subset_of_possible():
     legal = GEMM_SPACE.enumerate_legal(inputs)
     for cfg in legal[:50]:
         assert GEMM_SPACE.contains(cfg)
-        assert gemm_vmem_bytes(cfg, 16) <= VMEM_USABLE
+        assert gemm_vmem_bytes(cfg, 16) <= VMEM_LIMIT_BYTES
 
 
 @given(st.sampled_from([16, 32]),
@@ -40,7 +40,7 @@ def test_legality_invariants(bits, lm, ln, lk):
     for _ in range(20):
         cfg = {n: int(rng.choice(GEMM_SPACE.params[n])) for n in names}
         if GEMM_SPACE.is_legal(cfg, inputs):
-            assert gemm_vmem_bytes(cfg, bits) <= VMEM_USABLE
+            assert gemm_vmem_bytes(cfg, bits) <= VMEM_LIMIT_BYTES
             assert cfg["bm"] % 8 == 0 and cfg["bn"] % 128 == 0
             k_steps = -(-inputs["K"] // cfg["bk"])
             assert cfg["k_split"] <= k_steps

@@ -14,13 +14,13 @@ import json
 import threading
 import urllib.error
 import urllib.request
-import warnings
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core.backend import WallClockBackend
 from repro.core.space import gemm_input
 from repro.core.tuner import clear_tuners
 from repro.kernels import dispatch
@@ -362,22 +362,17 @@ def test_worker_trace_export_dumps_to_bus(tmp_path):
 # serving measurer + deferred measurement queue
 # ---------------------------------------------------------------------------
 
-def test_wallclock_off_hardware_warns_once_and_counts():
-    m = ServingMeasurer("wallclock")
-    inputs = gemm_input(256, 64, 512)
-    if jax.default_backend() == "tpu":
-        pytest.skip("fallback path needs a non-TPU host")
-    with pytest.warns(RuntimeWarning, match="without TPU hardware"):
-        tf = m("gemm", dict(CFG), inputs)
-    assert tf > 0.0
-    # warn ONCE: the second call must stay quiet
-    with warnings.catch_warnings(record=True) as record:
-        warnings.simplefilter("always")
-        m("gemm", dict(CFG), inputs)
-    assert not [w for w in record if issubclass(w.category, RuntimeWarning)]
-    assert m.stats()["counts"]["sim"] == 2
+def test_wallclock_off_hardware_raises():
+    """Wall clock times compiled kernels on a TPU; off one it refuses to
+    exist rather than hand back simulated numbers under its name."""
+    assert jax.default_backend() != "tpu"
+    with pytest.raises(RuntimeError, match="on a TPU"):
+        ServingMeasurer("wallclock")
+    with pytest.raises(RuntimeError, match="on a TPU"):
+        WallClockBackend().measure("gemm", dict(CFG),
+                                   gemm_input(256, 64, 512))
     text = get_registry().render_prometheus()
-    assert 'tunedb_measurements_total{backend="sim"} 2' in text
+    assert 'tunedb_measurements_total{backend="wallclock"}' not in text
 
 
 def test_measurer_records_always_kept_span():
