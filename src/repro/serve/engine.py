@@ -104,13 +104,6 @@ class ServeConfig:
     # plan registry the retune controller publishes each successful swap's
     # compiled plan to — the coordinator half of the follow protocol
     retune_publish: Optional[str] = None
-    # append per-decode-tick wall seconds to Engine.tick_times (benchmarks
-    # and the fleet acceptance test; off in production serving)
-    record_tick_times: bool = False
-    # most recent ticks kept in Engine.tick_times (a bounded deque): a
-    # long-running serve with record_tick_times on must not grow without
-    # bound; 0 keeps every tick (short benchmark runs only)
-    tick_times_cap: int = 4096
     # -- graceful degradation (docs/ROBUSTNESS.md) ----------------------------
     # per-request wall-clock deadline, enforced at decode-tick boundaries:
     # a request older than this retires with whatever tokens it has (active
@@ -380,27 +373,25 @@ class StoreAwareAdmission:
 _NULL_CTX = contextlib.nullcontext()
 
 
-class _TickTimes(list):
-    """Bounded tick-time buffer: a real list (slicing and iteration work
-    exactly as before) that keeps only the newest ``cap`` entries.  cap=0
-    keeps everything — short benchmark runs that want the full series."""
-
-    def __init__(self, cap: int = 0) -> None:
-        super().__init__()
-        self.cap = int(cap)
-
-    def append(self, item) -> None:
-        list.append(self, item)
-        if self.cap and len(self) > self.cap:
-            del self[: len(self) - self.cap]
+def _span(tr, name: str, **attrs):
+    """Child span ``name`` under the open root, or the shared no-op when
+    tracing is off (``tr`` None: no Tracer call at all)."""
+    return _NULL_CTX if tr is None else tr.span(name, **attrs)
 
 
 @dataclasses.dataclass
 class Request:
+    """One request and its stamps, all on ``time.perf_counter`` (the span
+    tracer's clock), always recorded whether or not tracing is on."""
+
     prompt: np.ndarray              # (len,) int32
     max_new: int
     out: List[int] = dataclasses.field(default_factory=list)
-    arrived_at: float = 0.0         # time.monotonic() at admission-queue entry
+    arrived_at: float = 0.0         # entry into the admission queue
+    admitted_at: Optional[float] = None   # admission into a slot began
+    # when each token of ``out`` reached the host, one stamp per token
+    token_times: List[float] = dataclasses.field(default_factory=list)
+    done_at: Optional[float] = None  # retired, shed or rejected
     shed: bool = False              # rejected unserved by load shedding
     deadline_exceeded: bool = False  # cut short / rejected by the deadline
 
@@ -554,15 +545,8 @@ class Engine:
         # and replays them per tick — true frequencies, not a compile census
         self._decode_shapes: Optional[List] = None
         self._prefill_shapes: Dict[int, List] = {}
-        # per-decode-tick (start perf_counter, wall seconds, thread-CPU
-        # seconds) when ServeConfig.record_tick_times — the fleet bench/test
-        # reads this.  Thread CPU time is the de-noised "did THIS thread do
-        # the work" clock: an inline retune session lands in it, scheduler
-        # preemption and other threads' work do not.  Bounded: a week-long
-        # serve with recording on keeps the newest tick_times_cap entries
-        # instead of growing without limit (a real list subclass, so the
-        # bench/test read surface — slicing, iteration — is unchanged).
-        self.tick_times = _TickTimes(serve_cfg.tick_times_cap)
+        # the requests of the latest generate call, with their stamps
+        self.last_requests: List[Request] = []
         # store-aware admission: reorder/group pending requests toward
         # plan-hit prefill shapes ("fifo" keeps arrival order)
         self.admission = (StoreAwareAdmission()
@@ -789,6 +773,7 @@ class Engine:
         self.slot_req[slot] = req
         tok = int(self._sample(np.asarray(logits)[:, : cfg.vocab])[0])
         req.out.append(tok)
+        req.token_times.append(time.perf_counter())
 
     def _sample(self, logits: np.ndarray) -> np.ndarray:
         if self.sc.temperature <= 0:
@@ -800,18 +785,22 @@ class Engine:
     # -- main loop --------------------------------------------------------------
     def generate(self, prompts: List[np.ndarray], max_new: int = 32
                  ) -> List[List[int]]:
-        """Continuous-batching loop: admit -> decode tick -> retire."""
-        cfg, sc = self.cfg, self.sc
-        t_arrive = time.monotonic()
+        """Continuous-batching loop: admit -> decode tick -> retire.
+
+        The call's requests, with their stamps, stay readable on
+        ``last_requests`` after it returns."""
+        sc = self.sc
+        t_arrive = time.perf_counter()
         queue = [Request(np.asarray(p, np.int32), max_new,
                          arrived_at=t_arrive) for p in prompts]
+        self.last_requests = queue
         pending = list(queue)
         active = 0
         # tracing: each admission and each decode tick opens its own trace
-        # root (sampled per trace_sample); router decisions, prefill,
-        # dispatch-tier resolutions, and idle-gap measurements nest under
-        # whichever root is open on this thread.  tr None = the untraced
-        # path, byte-identical to before, zero instrument calls.
+        # root (sampled per trace_sample); router decisions, prefill, the
+        # tick's phases, dispatch-tier resolutions, and idle-gap
+        # measurements nest under whichever root is open on this thread.
+        # tr None = the untraced path: zero instrument calls.
         tr = self.tracer
 
         while pending or active:
@@ -822,12 +811,13 @@ class Engine:
             # still meet their deadlines.  A shed/expired request keeps
             # whatever tokens it has; its flags say why it stopped.
             if sc.request_deadline_s is not None and pending:
-                now = time.monotonic()
+                now = time.perf_counter()
                 expired = [r for r in pending
                            if now - r.arrived_at > sc.request_deadline_s]
                 if expired:
                     for req in expired:
                         req.deadline_exceeded = True
+                        req.done_at = now
                     pending = [r for r in pending if not r.deadline_exceeded]
                     self.deadline_retired += len(expired)
                     self._count_degraded("rejected", len(expired))
@@ -836,6 +826,7 @@ class Engine:
                 while active + len(pending) > sc.shed_threshold:
                     req = pending.pop()          # newest arrival goes first
                     req.shed = True
+                    req.done_at = time.perf_counter()
                     shed_now += 1
                 if shed_now:
                     self.shed_requests += shed_now
@@ -853,10 +844,11 @@ class Engine:
                     nxt = self.admission.pick(pending, self._prefill_shapes,
                                               last_len=self._last_admit_len)
                 req = pending.pop(nxt)
+                req.admitted_at = time.perf_counter()
                 self._last_admit_len = len(req.prompt)
                 n = len(req.prompt)
                 with (tr.root("engine.admit", prompt_len=n)
-                      if tr is not None else _NULL_CTX):
+                      if tr is not None else _NULL_CTX) as root:
                     if self.router is not None:
                         # single-process engine: the decision is recorded
                         # (and scraped at /status) even though the only
@@ -864,52 +856,71 @@ class Engine:
                         # router object over several engines gets real
                         # placement from this call
                         self.router.route(self._prefill_shapes.get(n, []))
-                    with (tr.span("engine.prefill", prompt_len=n)
-                          if tr is not None else _NULL_CTX):
+                    # compiled: this length's program is traced (and
+                    # compiled or loaded from the compile cache) right here
+                    with _span(tr, "engine.prefill", prompt_len=n,
+                               compiled=n not in self._prefill_fns):
                         self._prefill_one(slot, req)
+                    if root is not None:
+                        root.set(
+                            queue_wait_s=req.admitted_at - req.arrived_at,
+                            ttft_s=req.token_times[0] - req.arrived_at)
                 active += 1
             if active == 0:
                 break
 
-            # one decode tick for every slot (idle slots run on garbage that
-            # is discarded — static shapes, zero recompiles)
-            from repro.tunedb.telemetry import get_telemetry
-            if sc.record_tick_times:
-                t_tick, c_tick = time.perf_counter(), time.thread_time()
-            with (tr.root("engine.tick", tick=self.ticks)
+            with (tr.root("engine.tick", tick=self.ticks, active=active)
                   if tr is not None else _NULL_CTX):
-                last = np.array([
-                    (r.out[-1] if r is not None and r.out else 0)
-                    for r in self.slot_req], np.int32)[:, None]
-                idx = jnp.asarray(self.lengths, jnp.int32)  # slot position
-                if self._decode_shapes is None:
-                    # compiling tick: the trace-time census IS this tick's
-                    # count
-                    with get_telemetry().capture() as cap:
-                        logits, self.cache = self._decode(
-                            self.params, jnp.asarray(last), self.cache, idx)
-                    self._decode_shapes = cap.shapes
-                else:
+                active -= self._decode_tick(tr)
+        return [r.out for r in queue]
+
+    def _decode_tick(self, tr) -> int:
+        """One decode tick for every slot, in five phases (each a child
+        span when traced); returns how many requests it retired.  Idle
+        slots run on garbage that is discarded — static shapes, zero
+        recompiles."""
+        from repro.tunedb.telemetry import get_telemetry
+
+        sc = self.sc
+        with _span(tr, "engine.tick.launch"):
+            last = np.array([
+                (r.out[-1] if r is not None and r.out else 0)
+                for r in self.slot_req], np.int32)[:, None]
+            idx = jnp.asarray(self.lengths, jnp.int32)  # slot position
+            if self._decode_shapes is None:
+                # compiling tick: the trace-time census IS this tick's count
+                with get_telemetry().capture() as cap:
                     logits, self.cache = self._decode(
                         self.params, jnp.asarray(last), self.cache, idx)
-                    if self._decode_shapes:
-                        get_telemetry().record_ticks(self._decode_shapes)
-                toks = self._sample(np.asarray(logits)[:, : cfg.vocab])
-                self.ticks += 1
-                # fold this tick's lock-free telemetry rings into the
-                # counters: one batched drain per tick instead of one lock
-                # per kernel call
-                get_telemetry().drain_pending()
-                self.maybe_retune()
-
-            now = (time.monotonic()
-                   if sc.request_deadline_s is not None else 0.0)
+                self._decode_shapes = cap.shapes
+            else:
+                logits, self.cache = self._decode(
+                    self.params, jnp.asarray(last), self.cache, idx)
+                if self._decode_shapes:
+                    get_telemetry().record_ticks(self._decode_shapes)
+        with _span(tr, "engine.tick.wait"):
+            logits.block_until_ready()
+        with _span(tr, "engine.tick.fetch"):
+            host = np.asarray(logits)
+        with _span(tr, "engine.tick.sample"):
+            toks = self._sample(host[:, : self.cfg.vocab])
+        # the tick's one clock read: every token of this tick reached the
+        # host by now, and the deadline is judged against it
+        now = time.perf_counter()
+        self.ticks += 1
+        retired = 0
+        with _span(tr, "engine.tick.retire"):
+            # fold this tick's lock-free telemetry rings into the counters:
+            # one batched drain per tick instead of one lock per kernel call
+            get_telemetry().drain_pending()
+            self.maybe_retune()
             for s, req in enumerate(self.slot_req):
                 if req is None:
                     continue
                 self.lengths[s] += 1
                 tok = int(toks[s])
                 req.out.append(tok)
+                req.token_times.append(now)
                 overdue = (sc.request_deadline_s is not None
                            and now - req.arrived_at > sc.request_deadline_s)
                 if overdue:
@@ -921,11 +932,8 @@ class Engine:
                 if (overdue or tok == sc.eos_token
                         or len(req.out) >= req.max_new
                         or self.lengths[s] + 1 >= sc.max_len):
+                    req.done_at = now
                     self.slot_req[s] = None
                     self.lengths[s] = 0
-                    active -= 1
-            if sc.record_tick_times:
-                self.tick_times.append((t_tick,
-                                        time.perf_counter() - t_tick,
-                                        time.thread_time() - c_tick))
-        return [r.out for r in queue]
+                    retired += 1
+        return retired

@@ -71,6 +71,7 @@ FLEET_TRACE_DIR = "traces"  # <fleet>/traces/<worker>.jsonl span dumps
 # Span-name taxonomy (docs/OBSERVABILITY.md documents the tree):
 #   request.route     router decision            engine.admit      admission
 #   engine.prefill    prefill compile+run        engine.tick       decode tick
+#   engine.tick.{launch,wait,fetch,sample,retire}  the tick's phases, in order
 #   dispatch.resolve  tier resolution            retune.epoch      submit->swap
 #   fleet.job         worker tuning session      fleet.merge       coordinator
 #   plan.install      follower install attempt   measure.*         wall-clock /
@@ -145,25 +146,52 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
+_ANNOTATION = None     # jax.profiler.TraceAnnotation once looked up; False
+                      # where JAX is not installed
+
+
+def _annotation():
+    """``jax.profiler.TraceAnnotation``, imported on first use so this
+    module imports without JAX; None where JAX is missing."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:
+            TraceAnnotation = False
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION or None
+
+
 class _SpanCtx:
     """Context manager that pushes a live span on the thread stack,
     stamps ``t0`` on enter and ``dur`` on exit, then hands the finished
-    span to the tracer's ring."""
+    span to the tracer's ring.  While a profiler session is active the
+    span also opens a ``TraceAnnotation`` of its name around itself, so
+    it stands in the profiler's host plane on the device ops' clock."""
 
-    __slots__ = ("_tracer", "_span")
+    __slots__ = ("_tracer", "_span", "_ann")
 
     def __init__(self, tracer: "Tracer", span: Span) -> None:
         self._tracer = tracer
         self._span = span
+        self._ann = None
 
     def __enter__(self) -> Span:
         self._tracer._push(self._span)
+        ann = _annotation()
+        if ann is not None and ann.is_enabled():
+            self._ann = ann(self._span.name)
+            self._ann.__enter__()
         self._span.t0 = time.perf_counter()
         return self._span
 
     def __exit__(self, et, ev, tb) -> bool:
         sp = self._span
         sp.dur = time.perf_counter() - sp.t0
+        if self._ann is not None:
+            self._ann.__exit__(et, ev, tb)
+            self._ann = None
         if et is not None:
             sp.attrs.setdefault("error", et.__name__)
         self._tracer._finish(sp)

@@ -32,6 +32,7 @@ from repro.tunedb.controller import RetuneConfig, RetuneController
 from repro.tunedb.fleet import (Coordinator, FleetJob, Worker,
                                 run_fleet_inline)
 from repro.tunedb.model import ModelSet, clear_models, get_models
+from repro.tunedb.obs.trace import reset_tracing
 from repro.tunedb.session import TuningSession, backend_fingerprint
 from repro.tunedb.__main__ import main as tunedb_main
 
@@ -46,6 +47,7 @@ def _clean_globals():
         clear_store()
         clear_models()
         clear_telemetry()
+        reset_tracing()
         dispatch.reset_fallback_warnings()
     reset()
     yield
@@ -702,7 +704,7 @@ def test_engine_async_retune_keeps_tick_p99_flat():
             ServeConfig(max_len=2048, slots=2, retune=True,
                         retune_async=True, retune_interval=256,
                         retune_min_calls=8, retune_top_k=2,
-                        retune_train=False, record_tick_times=True,
+                        retune_train=False, trace_sample=1.0,
                         retune_cooldown_ticks=100_000),  # exactly one epoch
             retune_tuners={"gemm": slow})
         controller = engine.controller
@@ -710,7 +712,7 @@ def test_engine_async_retune_keeps_tick_p99_flat():
 
         # warm the jit caches so compile never pollutes the timing window
         engine.generate([np.arange(4), np.arange(6)], max_new=8)
-        engine.tick_times.clear()
+        engine.tracer.clear()
         controller.reset_baseline()
         # synthetic drift: novel hot shapes the store has never seen
         tel = get_telemetry()
@@ -736,10 +738,10 @@ def test_engine_async_retune_keeps_tick_p99_flat():
 
         t_submit, t_done = controller.async_submit_t, controller.async_done_t
         assert t_submit is not None and t_done is not None
-        steady = [w for t0, w, _ in engine.tick_times[5:]
-                  if t0 + w < t_submit]
-        inflight = [w for t0, w, _ in engine.tick_times
-                    if t_submit <= t0 <= t_done]
+        ticks = sorted((s.t0, s.dur) for s in engine.tracer.spans()
+                       if s.name == "engine.tick")
+        steady = [w for t0, w in ticks[5:] if t0 + w < t_submit]
+        inflight = [w for t0, w in ticks if t_submit <= t0 <= t_done]
         assert len(steady) >= 100 and len(inflight) >= 100, \
             (len(steady), len(inflight))
         # Inline execution would park the polling tick for the whole ~0.8s

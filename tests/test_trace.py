@@ -409,22 +409,158 @@ def test_measure_queue_commits_winner_to_models_and_dedupes():
 
 
 # ---------------------------------------------------------------------------
-# tick_times bounding (satellite bugfix)
+# the engine's tick phases, admission stamps and request stamps
 # ---------------------------------------------------------------------------
 
-def test_tick_times_bounded_and_still_sliceable(small_model):
+PHASES = ["engine.tick.launch", "engine.tick.wait", "engine.tick.fetch",
+          "engine.tick.sample", "engine.tick.retire"]
+
+
+def _traced_run(small_model, lengths=(6, 7, 6), max_new=5, slots=2):
+    cfg, params = small_model
+    eng = Engine(cfg, params, ServeConfig(max_len=64, slots=slots,
+                                          trace_sample=1.0))
+    rng = np.random.default_rng(0)
+    eng.generate([rng.integers(0, 64, n) for n in lengths], max_new=max_new)
+    return eng, eng.tracer.spans()
+
+
+def test_each_tick_has_its_five_phases_in_order(small_model):
+    eng, spans = _traced_run(small_model)
+    ticks = [s for s in spans if s.name == "engine.tick"]
+    assert len(ticks) == eng.ticks > 2
+    children = {}
+    for s in spans:
+        if s.name.startswith("engine.tick."):
+            children.setdefault(s.parent_id, []).append(s)
+    for t in ticks:
+        assert 1 <= t.attrs["active"] <= 2
+        kids = sorted(children[t.span_id], key=lambda s: s.t0)
+        assert [k.name for k in kids] == PHASES
+        assert all(k.trace_id == t.trace_id for k in kids)
+        # nested in the tick, one after another; the retire loop inside
+        assert t.t0 <= kids[0].t0
+        assert kids[-1].t0 + kids[-1].dur <= t.t0 + t.dur
+        for a, b in zip(kids, kids[1:]):
+            assert a.t0 + a.dur <= b.t0
+    # the retire loop runs inside its tick: every token a tick appended,
+    # and every retirement, is stamped within that tick's span
+    inside = [(t.t0, t.t0 + t.dur) for t in ticks]
+    for r in eng.last_requests:
+        for stamp in (*r.token_times[1:], r.done_at):
+            assert any(a <= stamp <= b for a, b in inside)
+
+
+def test_admission_carries_queue_wait_and_ttft(small_model):
+    _eng, spans = _traced_run(small_model, lengths=(6, 7, 6, 8))
+    admits = [s for s in spans if s.name == "engine.admit"]
+    assert len(admits) == 4
+    for a in admits:
+        assert a.attrs["queue_wait_s"] >= 0.0
+        assert a.attrs["ttft_s"] >= a.attrs["queue_wait_s"]
+    # two slots: the later two waited for the first two to retire
+    assert sorted(a.attrs["queue_wait_s"] for a in admits)[-1] > 0.0
+
+
+def test_prefill_marks_the_admission_that_compiled(small_model):
+    _eng, spans = _traced_run(small_model, lengths=(6, 7, 6, 7, 9))
+    seen = [(s.attrs["prompt_len"], s.attrs["compiled"]) for s in spans
+            if s.name == "engine.prefill"]
+    assert seen == [(6, True), (7, True), (6, False), (7, False), (9, True)]
+
+
+@pytest.mark.parametrize("trace_sample", [0.0, 1.0])
+def test_request_stamps_are_ordered_on_the_tracer_clock(small_model,
+                                                        trace_sample):
+    import time
     cfg, params = small_model
     eng = Engine(cfg, params, ServeConfig(max_len=64, slots=2,
-                                          record_tick_times=True,
-                                          tick_times_cap=8))
-    rng = np.random.default_rng(0)
-    eng.generate([rng.integers(0, 128, 6) for _ in range(3)], max_new=16)
-    assert eng.ticks > 8                    # enough ticks to overflow cap
-    assert len(eng.tick_times) == 8         # bounded: newest 8 kept
-    assert isinstance(eng.tick_times, list)
-    tail = eng.tick_times[5:]               # bench/test read surface: slices
-    assert len(tail) == 3
-    assert all(w > 0.0 for _t0, w, _ in eng.tick_times)
+                                          trace_sample=trace_sample))
+    rng = np.random.default_rng(1)
+    t0 = time.perf_counter()
+    outs = eng.generate([rng.integers(0, 64, n) for n in (6, 5, 7)],
+                        max_new=4)
+    t1 = time.perf_counter()
+    assert [r.out for r in eng.last_requests] == outs
+    for r in eng.last_requests:
+        stamps = [r.arrived_at, r.admitted_at, *r.token_times, r.done_at]
+        assert stamps == sorted(stamps)
+        assert t0 <= r.arrived_at and r.done_at <= t1
+        assert len(r.token_times) == len(r.out) == 4
+
+
+def test_untraced_generate_calls_no_tracer_and_no_annotation(
+        small_model, monkeypatch):
+    from jax.profiler import TraceAnnotation
+
+    from repro.tunedb.obs import trace as trace_mod
+
+    cfg, params = small_model
+    calls = []
+
+    class CountingAnnotation(TraceAnnotation):
+        """The annotation class the span tracer opens, counting its use
+        (JAX's own annotations of compiles and transfers stay uncounted)."""
+
+        def __init__(self, *a, **kw):
+            calls.append("annotation")
+            super().__init__(*a, **kw)
+
+        @staticmethod
+        def is_enabled():
+            calls.append("is_enabled")
+            return TraceAnnotation.is_enabled()
+
+    monkeypatch.setattr(trace_mod, "_ANNOTATION", CountingAnnotation)
+    for name in ("root", "span", "begin", "end"):
+        orig = getattr(Tracer, name)
+
+        def wrapped(*a, _orig=orig, _name=name, **kw):
+            calls.append(_name)
+            return _orig(*a, **kw)
+        monkeypatch.setattr(Tracer, name, wrapped)
+    prompts = [np.arange(6), np.arange(7), np.arange(5)]
+
+    eng = Engine(cfg, params, ServeConfig(max_len=64, slots=2))
+    eng.generate(prompts, max_new=6)
+    assert eng.ticks > 0 and calls == []
+    # the same counters do see a traced engine, and the annotation opens
+    # only while a profiler session is active
+    eng = Engine(cfg, params, ServeConfig(max_len=64, slots=2,
+                                          trace_sample=1.0))
+    eng.generate(prompts, max_new=6)
+    assert {"root", "span", "is_enabled"} <= set(calls)
+    assert "annotation" not in calls
+
+
+def test_engine_spans_stand_in_the_profilers_host_plane(small_model,
+                                                        tmp_path):
+    import collections
+    import glob
+
+    from jax.profiler import ProfileData
+
+    cfg, params = small_model
+    eng = Engine(cfg, params, ServeConfig(max_len=64, slots=2,
+                                          trace_sample=1.0))
+    prompts = [np.arange(6), np.arange(7), np.arange(5)]
+    eng.generate(prompts, max_new=6)            # compile outside the trace
+    eng.tracer.clear()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        eng.generate(prompts, max_new=6)
+    finally:
+        jax.profiler.stop_trace()
+    want = collections.Counter(s.name for s in eng.tracer.spans()
+                               if s.name.startswith("engine."))
+    [path] = glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True)
+    got = collections.Counter(
+        ev.name for plane in ProfileData.from_file(path).planes
+        if plane.name.startswith("/host") for line in plane.lines
+        for ev in line.events if ev.name.startswith("engine."))
+    assert want["engine.tick"] > 2
+    assert set(PHASES) <= set(want)
+    assert got == want
 
 
 @pytest.fixture(scope="module")
