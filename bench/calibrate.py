@@ -39,7 +39,7 @@ def main(argv=None) -> int:
 
     from bench import correctness
     from bench.harness import Cell, check_device
-    from bench.model import ModelSpec, make_weights, round_up, seed_key
+    from bench.model import ModelSpec, round_up, seed_key
     from bench.traffic import Calls, Mix
 
     cell = Cell.load(args.workload)
@@ -50,7 +50,7 @@ def main(argv=None) -> int:
     limits = correctness.Limits.load(cell.name)
     engine = None
     for seed in (int(s) for s in args.seeds.split(",")):
-        weights = make_weights(spec, seed_key(seed))
+        weights = spec.make_weights(seed_key(seed))
         if engine is None:
             engine = Engine(spec.program_config(), weights,
                             ServeConfig(slots=spec.slots, max_len=spec.max_len))

@@ -17,15 +17,36 @@ import glob
 import re
 import threading
 import time
-from typing import Iterable, List, Optional, Sequence, Tuple
+from typing import Iterable, List, NamedTuple, Optional, Sequence, Tuple
 
 ANNOTATION = "bench.window"
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE = "XLA Ops"
 CONTAINERS = ("while", "conditional", "call")
 
-_SHAPE = re.compile(r"\b([a-z]+\d*)\[([\d,]*)\]")
+_ARRAY = re.compile(r"\b([a-z]+\d*)\[([\d,]*)\](?:\{([^{}]*)\})?")
+_SPACE = re.compile(r"S\((\d+)\)")
 _OP = re.compile(r"^%\S+\s*=\s*(.*?)\s+([a-z][\w\-]*)\(")
+HBM = 0
+
+
+class Array(NamedTuple):
+    """An array of an instruction: its type, dims and memory space, the
+    layout's ``S(n)``: ``S(1)`` marks an array the compiler keeps in the
+    core's on-chip VMEM, and a layout without one is in HBM (0)."""
+
+    dtype: str
+    dims: Tuple[int, ...]
+    space: int
+
+
+def _arrays(text: str) -> List[Array]:
+    out = []
+    for dtype, dims, layout in _ARRAY.findall(text):
+        space = _SPACE.search(layout)
+        out.append(Array(dtype, tuple(int(x) for x in dims.split(",") if x),
+                         int(space.group(1)) if space else HBM))
+    return out
 
 
 @dataclasses.dataclass(frozen=True)
@@ -54,8 +75,13 @@ class Op:
         shape = re.sub(r"\{[^{}]*\}", "", m.group(1))
         return f"{m.group(2)} {shape}"[:80]
 
-    def operand_shapes(self) -> List[Tuple[str, Tuple[int, ...]]]:
-        """(dtype, dims) of each operand, read from the instruction text."""
+    def results(self) -> List[Array]:
+        """The result's arrays (one, or each of a tuple's)."""
+        m = _OP.match(self.name)
+        return _arrays(m.group(1)) if m else []
+
+    def operands(self) -> List[Array]:
+        """Each operand's array, read from the instruction text."""
         m = _OP.match(self.name)
         if not m:
             return []
@@ -68,9 +94,7 @@ class Op:
             elif c == ")":
                 depth -= 1
             i += 1
-        text = re.sub(r"\{[^{}]*\}", "", self.name[start:i - 1])
-        return [(dt, tuple(int(x) for x in dims.split(",") if x))
-                for dt, dims in _SHAPE.findall(text)]
+        return _arrays(self.name[start:i - 1])
 
 
 @dataclasses.dataclass

@@ -107,7 +107,7 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
     from repro.launch.compile_cache import enable_compile_cache
 
     from . import correctness
-    from .model import ModelSpec, make_weights, round_up, seed_key
+    from .model import ModelSpec, round_up, seed_key
     from .observe import Observations, Request, read_metric
     from .peaks import peak_for
     from .traffic import Calls, Mix
@@ -125,7 +125,7 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
 
     from repro.serve.engine import Engine, ServeConfig
     factory = engine_factory or Engine
-    weights = make_weights(spec, seed_key(seed))
+    weights = spec.make_weights(seed_key(seed))
     jax.block_until_ready(weights)
     tracer = None
     if trace:

@@ -67,15 +67,15 @@ def _layer(x, lw, *, spec, low):
     mm = lambda a, w: jnp.dot(q8(a), q8(f32(w)), precision=HI)
     S = x.shape[0]
     pos = jnp.arange(S)
-    h = _rms(x, f32(lw["norm1"]), spec.norm_eps)
+    h = _rms(x, f32(lw["norm1"]), s.norm_eps)
     a = lw["attn"]
     q = mm(h, a["wq"]).reshape(S, s.n_heads, s.head_dim)
     k = mm(h, a["wk"]).reshape(S, s.n_kv, s.head_dim)
     v = mm(h, a["wv"]).reshape(S, s.n_kv, s.head_dim)
-    if spec.qk_norm:
-        q = _rms(q, f32(a["q_norm"]), spec.norm_eps)
-        k = _rms(k, f32(a["k_norm"]), spec.norm_eps)
-    q, k = _rope(q, pos, spec.rope_theta), _rope(k, pos, spec.rope_theta)
+    if s.qk_norm:
+        q = _rms(q, f32(a["q_norm"]), s.norm_eps)
+        k = _rms(k, f32(a["k_norm"]), s.norm_eps)
+    q, k = _rope(q, pos, s.rope_theta), _rope(k, pos, s.rope_theta)
     q, k, v = q8(q), q8(k), q8(v)
     rep = s.n_heads // s.n_kv
     k, v = jnp.repeat(k, rep, axis=1), jnp.repeat(v, rep, axis=1)
@@ -89,7 +89,7 @@ def _layer(x, lw, *, spec, low):
         outs.append(jnp.einsum("hqk,khd->qhd", p, v, precision=HI))
     o = jnp.concatenate(outs, 0).reshape(S, s.n_heads * s.head_dim)
     x = x + mm(o, a["wo"])
-    h = _rms(x, f32(lw["norm2"]), spec.norm_eps)
+    h = _rms(x, f32(lw["norm2"]), s.norm_eps)
     m = lw["mlp"]
     return x + mm(jax.nn.silu(mm(h, m["w_gate"])) * mm(h, m["w_up"]),
                   m["w_down"])
@@ -125,7 +125,7 @@ class Reference:
                     layers), spec=spec, low=low))
         rows = weights["embed"].shape[0]
         self._slice = _slice_rows(rows)
-        eps = spec.norm_eps
+        eps = spec.shape.norm_eps
 
         @jax.jit
         def final(x, norm, pos):

@@ -7,7 +7,7 @@ import pytest
 
 TINY_CONFIG = {
     "name": "tiny", "source": "test", "program": "qwen3-14b",
-    "reference": "dense_gqa", "architecture": {"qk_norm": True},
+    "reference": "dense_gqa", "architecture": {"kind": "dense_gqa", "qk_norm": True},
     "config": {"hidden_size": 64, "intermediate_size": 128,
                "num_attention_heads": 4, "num_key_value_heads": 2,
                "head_dim": 16, "num_hidden_layers": 2, "rms_norm_eps": 1e-6,

@@ -9,7 +9,7 @@ import numpy as np
 import pytest
 
 from bench.correctness import Limits, checks, compare, control_checks, passed
-from bench.model import ModelSpec, make_weights, seed_key
+from bench.model import ModelSpec, seed_key
 
 from .conftest import TINY_CONFIG
 
@@ -29,13 +29,13 @@ LIMITS = Limits(max_logit_gap=0.04, sample_tokens=64)
 def engine():
     from repro.serve.engine import Engine, ServeConfig
 
-    return Engine(SPEC.program_config(), make_weights(SPEC, seed_key(0)),
+    return Engine(SPEC.program_config(), SPEC.make_weights(seed_key(0)),
                   ServeConfig(slots=8, max_len=128))
 
 
 @pytest.mark.parametrize("seed", [1, 2, 2 ** 31 + 7])
 def test_float8_control_reads_far_above_the_served_gap(engine, seed):
-    w = make_weights(SPEC, seed_key(seed))
+    w = SPEC.make_weights(seed_key(seed))
     engine.params = w
     rng = np.random.default_rng(seed)
     prompts = [rng.integers(0, 4096, int(n), dtype=np.int32)

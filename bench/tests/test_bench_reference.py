@@ -9,14 +9,14 @@ import numpy as np
 import pytest
 
 from bench.correctness import reference_for
-from bench.model import ModelSpec, make_weights, seed_key
+from bench.model import ModelSpec, seed_key
 
 from .conftest import TINY_CONFIG
 
 
 def spec_for(program, qk_norm, tied=True):
     raw = {**TINY_CONFIG, "program": program,
-           "architecture": {"qk_norm": qk_norm},
+           "architecture": {"kind": "dense_gqa", "qk_norm": qk_norm},
            "config": {**TINY_CONFIG["config"], "torch_dtype": "float32",
                       "num_hidden_layers": 3,
                       "tie_word_embeddings": tied}}
@@ -31,7 +31,7 @@ def test_reference_matches_prefill_and_decode_through_the_cache(
 
     spec = spec_for(program, qk_norm)
     cfg = spec.program_config()
-    w = make_weights(spec, seed_key(7))
+    w = spec.make_weights(seed_key(7))
     rng = np.random.default_rng(0)
     prompt = rng.integers(0, spec.shape.vocab, 21, dtype=np.int32)
     new = rng.integers(0, spec.shape.vocab, 5, dtype=np.int32)
@@ -57,7 +57,7 @@ def test_reference_matches_prefill_and_decode_through_the_cache(
 
 def test_padding_past_the_sequence_changes_no_logit():
     spec = spec_for("qwen3-14b", True)
-    w = make_weights(spec, seed_key(3))
+    w = spec.make_weights(seed_key(3))
     toks = np.arange(1, 17, dtype=np.int32)
     a = reference_for(spec, w, length=32, n_positions=4)
     b = reference_for(spec, w, length=64, n_positions=4)
@@ -91,9 +91,9 @@ def test_e4m3_rounding():
 def test_weights_follow_the_served_dtype_and_seed():
     spec = dataclasses.replace(spec_for("qwen3-14b", True),
                                dtype=jnp.bfloat16)
-    a = make_weights(spec, seed_key(2 ** 31 + 3))
-    b = make_weights(spec, seed_key(2 ** 31 + 3))
-    c = make_weights(spec, seed_key(4))
+    a = spec.make_weights(seed_key(2 ** 31 + 3))
+    b = spec.make_weights(seed_key(2 ** 31 + 3))
+    c = spec.make_weights(seed_key(4))
     assert a["embed"].dtype == jnp.bfloat16
     assert a["embed"].shape == (512, 64)
     assert a["layers"]["pos0"]["attn"]["wq"].shape == (3, 64, 64)
