@@ -6,6 +6,7 @@ projection shape the benchmark's configurations serve.
 
     python -m benchmarks.bench_kernels            # the whole harness
     python -m benchmarks.bench_kernels --sweep    # the GEMM sweep alone
+    python -m benchmarks.bench_kernels --gmm      # the grouped-GEMM sweep
 """
 
 from __future__ import annotations
@@ -35,6 +36,15 @@ CENSUS = {
                   [16, 256, 1536, 2560, 3840]),
     "smollm-135m": ([(576, 576), (192, 576), (1536, 576), (576, 1536)],
                     [64, 128, 768, 1920]),
+}
+
+# each expert GEMM (N, K) of a sparse-expert configuration, its experts and
+# experts per token, and the token counts its cells send: decode slots and
+# prefill lengths (the rows are tokens x top_k)
+GMM_CENSUS = {
+    "mellum2-12b-a2.5b": {"experts": 64, "top_k": 8,
+                          "nks": [(896, 2304), (2304, 896)],
+                          "tokens": [16, 1024, 4096, 12288]},
 }
 
 CASES = {
@@ -139,6 +149,113 @@ def sweep() -> list:
     return rows
 
 
+def _seconds(fn, *args, min_s: float = 0.1, repeats: int = 3) -> float:
+    """Seconds a call, timed as ``_tflops`` times."""
+    f = jax.jit(fn)
+    for _ in range(2):
+        jax.block_until_ready(f(*args))
+
+    def batch(n):
+        t0 = time.perf_counter()
+        for _ in range(n):
+            out = f(*args)
+        jax.block_until_ready(out)
+        return time.perf_counter() - t0
+
+    n = 1
+    while batch(n) < min_s:
+        n *= 2
+    return min(batch(n) for _ in range(repeats)) / n
+
+
+def _routed_sizes(tokens: int, experts: int, top_k: int) -> jax.Array:
+    """Rows per expert of ``tokens`` routed top-k by random logits."""
+    logits = jax.random.normal(jax.random.PRNGKey(tokens), (tokens, experts))
+    _, idx = jax.lax.top_k(logits, top_k)
+    return jnp.bincount(idx.reshape(-1), length=experts).astype(jnp.int32)
+
+
+def gmm_candidates(M: int, N: int, K: int) -> list:
+    """(tm, tk, tn) tiles tried: rows 128-512 (fewer where M is small),
+    the whole K and N or half of either where that stays lane-aligned."""
+    tms = [t for t in (32, 64, 128, 256, 512) if t <= max(M, 128)][-3:]
+    tks = [K] + ([K // 2] if (K // 2) % 128 == 0 else [])
+    tns = [N] + ([N // 2] if (N // 2) % 128 == 0 else [])
+    return [(tm, tk, tn) for tm in tms for tk in tks for tn in tns]
+
+
+def gmm_sweep() -> list:
+    """TFLOP/s of each expert GEMM of ``GMM_CENSUS`` at each token count,
+    bf16, compiled on the attached TPU, with the weights as the served path
+    passes them (two layers stacked, one layer's groups used): the Pallas
+    grouped GEMM at each candidate tiling, the shape rule's pick and XLA's
+    ragged dot; then the whole served MoE layer at decode against the
+    dense-over-experts form."""
+    from repro.core.space import gmm_default_tiles
+    from repro.models.moe import init_moe, moe_decode, moe_dropless
+
+    rows = []
+    for model, c in GMM_CENSUS.items():
+        E, k = c["experts"], c["top_k"]
+        for N, K in c["nks"]:
+            # as served: two layers' experts stacked and flattened, the
+            # second layer's groups holding the rows (the first's empty)
+            rhs = jax.random.normal(jax.random.PRNGKey(N + K), (2 * E, K, N),
+                                    jnp.float32).astype(jnp.bfloat16)
+            for T in c["tokens"]:
+                M = T * k
+                gs = jnp.zeros((2 * E,), jnp.int32).at[E:].set(
+                    _routed_sizes(T, E, k))
+                lhs = jax.random.normal(jax.random.PRNGKey(M), (M, K),
+                                        jnp.float32).astype(jnp.bfloat16)
+                flops = 2.0 * M * N * K
+                tried = {}
+                for tiles in gmm_candidates(M, N, K):
+                    try:
+                        dt = _seconds(lambda a, b, g, t=tiles:
+                                      ops.grouped_matmul(a, b, g, t,
+                                                         interpret=False),
+                                      lhs, rhs, gs)
+                        tried["x".join(map(str, tiles))] = flops / dt / 1e12
+                    except Exception as e:         # refused by the compiler
+                        tried["x".join(map(str, tiles))] = \
+                            f"failed: {str(e)[:60]}"
+                ragged = flops / _seconds(jax.lax.ragged_dot, lhs, rhs,
+                                          gs) / 1e12
+                ok = {t: v for t, v in tried.items() if isinstance(v, float)}
+                best = max(ok, key=ok.get) if ok else None
+                pick = gmm_default_tiles(M, N, K, 16)
+                row = {"model": model, "T": T, "M": M, "N": N, "K": K,
+                       "pick": "ragged_dot" if pick is None
+                       else "x".join(map(str, pick)),
+                       "best": best,
+                       "best_tflops": round(ok[best], 3) if best else None,
+                       "ragged_dot": round(ragged, 3),
+                       "all": {t: (round(v, 3) if isinstance(v, float)
+                                   else v) for t, v in tried.items()}}
+                rows.append(row)
+                print(" ".join(f"{k2}={v}" for k2, v in row.items()),
+                      flush=True)
+        # the whole layer at the decode token count
+        D, F = c["nks"][0][1], c["nks"][0][0]
+        p = init_moe(jax.random.PRNGKey(0), D, F, E, jnp.bfloat16)
+        for T in c["tokens"][:2]:
+            x = jax.random.normal(jax.random.PRNGKey(T), (T, 1, D),
+                                  jnp.float32).astype(jnp.bfloat16)
+            drop = _seconds(lambda pp, xx: moe_dropless(
+                pp, xx, n_experts=E, top_k=k)[0], p, x)
+            dense = _seconds(lambda pp, xx: moe_decode(
+                pp, xx, n_experts=E, top_k=k), p, x)
+            row = {"model": model, "layer_tokens": T,
+                   "dropless_ms": round(1e3 * drop, 4),
+                   "dense_over_experts_ms": round(1e3 * dense, 4)}
+            rows.append(row)
+            print(" ".join(f"{k2}={v}" for k2, v in row.items()), flush=True)
+    save("gmm_sweep", {"device": jax.devices()[0].device_kind,
+                       "rows": rows})
+    return rows
+
+
 def run(fast: bool = True) -> dict:
     interp = InterpretBackend()
     rows = []
@@ -185,9 +302,12 @@ if __name__ == "__main__":
     p = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     p.add_argument("--sweep", action="store_true",
                    help="only the GEMM sweep of the census shapes (TPU)")
-    if p.parse_args().sweep:
+    p.add_argument("--gmm", action="store_true",
+                   help="only the grouped-GEMM sweep of GMM_CENSUS (TPU)")
+    args = p.parse_args()
+    if args.sweep or args.gmm:
         if jax.default_backend() != "tpu":
-            raise SystemExit("the GEMM sweep times compiled kernels on a TPU")
-        sweep()
+            raise SystemExit("the sweeps time compiled kernels on a TPU")
+        sweep() if args.sweep else gmm_sweep()
     else:
         run()

@@ -18,6 +18,7 @@ _MODULES: Dict[str, str] = {
     "whisper-base": "whisper_base",
     "mamba2-1.3b": "mamba2_1p3b",
     "jamba-v0.1-52b": "jamba_v01_52b",
+    "mellum2-12b-a2.5b": "mellum2_12b_a2p5b",
 }
 
 ARCH_NAMES: List[str] = list(_MODULES)

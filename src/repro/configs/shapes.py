@@ -112,8 +112,10 @@ def cache_specs(cfg: ModelConfig, batch: int, max_len: int,
     cache: Dict[str, Any] = {}
     R = cfg.n_repeats
     for i, (mixer, _) in enumerate(cfg.pattern):
-        if mixer == "attn":
-            kv = _sds((R, batch, max_len, cfg.n_kv, cfg.hd), cfg.dtype, mesh,
+        if mixer in ("attn", "attn_window"):
+            length = (min(cfg.sliding_window, max_len)
+                      if mixer == "attn_window" else max_len)
+            kv = _sds((R, batch, length, cfg.n_kv, cfg.hd), cfg.dtype, mesh,
                       ("none", "batch", "seq", "none", "none"))
             cache[f"pos{i}"] = {"attn": {"k": kv, "v": kv}}
         else:

@@ -18,7 +18,8 @@ from __future__ import annotations
 
 import dataclasses
 import itertools
-from typing import Callable, Dict, Iterable, List, Mapping, Tuple
+from typing import (Callable, Dict, Iterable, List, Mapping, Optional,
+                    Tuple)
 
 # ---------------------------------------------------------------------------
 # Hardware constants for legality checks (TPU v5e target; see DESIGN.md §2).
@@ -152,6 +153,16 @@ def gemm_default_config(M: int, N: int, K: int, dtype_bits: int = 16
              default=_round_up(_ceil_div(M, _ceil_div(M, 512)), sub))
     return {"bm": bm, "bn": widest(N), "bk": widest(K), "k_unroll": 1,
             "k_split": 1, "order": 0, "acc32": 1, "prefetch": 2}
+
+
+def gmm_default_tiles(M: int, N: int, K: int, dtype_bits: int = 16
+                      ) -> Optional[Tuple[int, int, int]]:
+    """(tm, tk, tn) of the Pallas grouped GEMM of ``M`` rows against
+    (groups, K, N) weights, from the shape alone; None where XLA's ragged
+    dot is the faster implementation."""
+    sub = SUBLANE * max(32 // dtype_bits, 1)
+    tm = min(128, _round_up(M, sub))
+    return tm, K, N
 
 
 def gemm_is_legal(cfg: Mapping[str, int], inputs: Mapping[str, int]) -> bool:

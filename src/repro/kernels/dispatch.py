@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.space import gemm_default_config
+from repro.core.space import gemm_default_config, gmm_default_tiles
 
 from . import ops, ref
 
@@ -294,6 +294,25 @@ def matmul2(x: jax.Array, w: jax.Array, *, prefer_kernel: bool = False
     _record("gemm", gemm_input(M, w.shape[-1], x.shape[-1],
                                _dtype_bits(x.dtype)))
     return jnp.dot(x, w)
+
+
+def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
+                   *, prefer_kernel: bool = False) -> jax.Array:
+    """Grouped expert GEMM: ``lhs`` (M, K) holds the rows of each group in
+    turn, ``group_sizes`` (G,) how many each has (known only at run time),
+    and group g's rows are multiplied by ``rhs[g]`` (K, N) -> (M, N).
+
+    On a TPU (or with ``prefer_kernel``) the Pallas grouped GEMM runs with
+    tiles from the shape (``gmm_default_tiles``); where the rule names no
+    tiles, and off a TPU, XLA's ragged dot runs."""
+    M, K = lhs.shape
+    N = rhs.shape[2]
+    tiles = None
+    if on_tpu() or prefer_kernel:
+        tiles = gmm_default_tiles(M, N, K, _dtype_bits(lhs.dtype))
+    if tiles is None:
+        return jax.lax.ragged_dot(lhs, rhs, group_sizes)
+    return ops.grouped_matmul(lhs, rhs, group_sizes, tiles)
 
 
 def conv2d(i: jax.Array, f: jax.Array, *, prefer_kernel: bool = False

@@ -5,7 +5,7 @@ models call them through ``dispatch`` which injects tuned configurations.
 
 from __future__ import annotations
 
-from typing import Dict, Mapping, Optional
+from typing import Dict, Mapping, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -16,6 +16,7 @@ from . import attention as _attention
 from . import conv as _conv
 from . import matmul as _matmul
 from . import ssd as _ssd
+from .common import interpret_mode
 
 DEFAULT_CONV = {"b_npq": 128, "b_k": 128, "b_c": 128, "rs_unroll": 1,
                 "c_split": 1, "order": 0, "acc32": 1, "prefetch": 2}
@@ -69,6 +70,21 @@ def matmul(a: jax.Array, b: jax.Array,
     parts = _matmul.matmul_pallas(a_p, b_p, cfg, interpret=interpret)
     out = parts.sum(axis=0) if ks > 1 else parts[0]
     return out[:M, :N]
+
+
+def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
+                   tiles: Tuple[int, int, int], *,
+                   interpret: Optional[bool] = None) -> jax.Array:
+    """Rows of ``lhs`` (M, K), sorted by group, each times its group's
+    ``rhs[g]`` (K, N), through megablox's Pallas grouped GEMM with
+    ``tiles`` (tm, tk, tn).  M pads to the row tile; the padded rows belong
+    to no group and are cut off."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    M = lhs.shape[0]
+    out = gmm(_pad_to(lhs, 0, tiles[0]), rhs, group_sizes.astype(jnp.int32),
+              lhs.dtype, tuple(tiles), interpret=interpret_mode(interpret))
+    return out[:M]
 
 
 def conv2d(i: jax.Array, f: jax.Array,

@@ -13,11 +13,13 @@ Conventions:
 
 from __future__ import annotations
 
+import dataclasses
 import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.kernels import dispatch
 
@@ -55,15 +57,52 @@ def rope_freqs(head_dim: int, theta: float) -> jax.Array:
                             / head_dim))
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+@dataclasses.dataclass(frozen=True)
+class YaRN:
+    """YaRN RoPE scaling, as transformers' ``_compute_yarn_parameters``
+    defines it: the inverse frequencies blend from extrapolated (1/theta^i)
+    to interpolated (1/(factor theta^i)) over a linear ramp between the
+    dimensions where ``beta_fast`` and ``beta_slow`` rotations fit the
+    original context, and cos and sin scale by ``attention_factor``."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float
+    beta_slow: float
+    attention_factor: float
+
+    def inv_freq(self, head_dim: int, theta: float) -> np.ndarray:
+        def dim_of(rotations):
+            return (head_dim * math.log(self.original_max_position
+                                        / (rotations * 2 * math.pi))
+                    / (2 * math.log(theta)))
+
+        low = max(math.floor(dim_of(self.beta_fast)), 0)
+        high = min(math.ceil(dim_of(self.beta_slow)), head_dim - 1)
+        if low == high:
+            high += 0.001
+        i = np.arange(head_dim // 2, dtype=np.float32)
+        ramp = np.clip((i - low) / (high - low), 0.0, 1.0)
+        extrapolate = 1.0 - ramp
+        base = theta ** (np.arange(0, head_dim, 2, dtype=np.float32)
+                         / head_dim)
+        return ((1.0 / (self.factor * base)) * ramp
+                + (1.0 / base) * extrapolate).astype(np.float32)
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               yarn: Optional[YaRN] = None) -> jax.Array:
     """x (B, S, H, D); positions (B, S) or (S,)."""
     d = x.shape[-1]
-    freqs = rope_freqs(d, theta)                       # (d/2,)
+    freqs = (rope_freqs(d, theta) if yarn is None
+             else jnp.asarray(yarn.inv_freq(d, theta)))        # (d/2,)
     if positions.ndim == 1:
         positions = positions[None, :]
     ang = positions[..., None].astype(jnp.float32) * freqs  # (B, S, d/2)
     cos = jnp.cos(ang)[:, :, None, :]
     sin = jnp.sin(ang)[:, :, None, :]
+    if yarn is not None:
+        cos, sin = cos * yarn.attention_factor, sin * yarn.attention_factor
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)
@@ -223,6 +262,107 @@ def _block_causal_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     return out.astype(q.dtype)
 
 
+def _masked_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                      q_pos: jax.Array, kv_pos: jax.Array,
+                      window: int) -> jax.Array:
+    """Attention of q (B, Sq, H, D) over k/v (B, L, G, D) whose keys sit at
+    ``kv_pos`` (B, L) (negative: no key): a query at ``q_pos`` (B, Sq)
+    attends to keys with 0 <= q_pos - kv_pos < window.  Scores in f32,
+    contracted per KV group."""
+    B, Sq, H, D = q.shape
+    G = k.shape[2]
+    qf = q.astype(jnp.float32).reshape(B, Sq, G, H // G, D) / math.sqrt(D)
+    s = jnp.einsum("bqgrd,bkgd->bgrqk", qf, k.astype(jnp.float32))
+    dist = q_pos[:, :, None] - kv_pos[:, None, :]             # (B, Sq, L)
+    mask = (kv_pos[:, None, :] >= 0) & (dist >= 0) & (dist < window)
+    s = jnp.where(mask[:, None, None], s, -1e30)
+    p = jax.nn.softmax(s, axis=-1)
+    out = jnp.einsum("bgrqk,bkgd->bqgrd", p, v.astype(jnp.float32))
+    return out.reshape(B, Sq, H, D).astype(q.dtype)
+
+
+def _band_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                    q_pos: jax.Array, kv_pos: jax.Array, window: int,
+                    block: int, unroll: bool = False) -> jax.Array:
+    """Windowed attention over a whole step whose work is bounded by the
+    band, not by the keys' length: q (B, S, H, D) are the last S of the
+    P + S keys k/v (B, P + S, G, D), so query i's own key is key P + i.
+    Queries go in blocks of ``block``, each against the block + window - 1
+    keys its window can reach."""
+    B, S = q.shape[:2]
+    P = k.shape[1] - S
+    block = min(block, S)
+    n = -(-S // block)
+    front = max(window - 1 - P, 0)          # keys before the first window
+    back = n * block - S
+    pad = lambda t, f, b, val=0: jnp.pad(
+        t, [(0, 0), (f, b)] + [(0, 0)] * (t.ndim - 2), constant_values=val)
+    kx, vx = pad(k, front, back), pad(v, front, back)
+    px = pad(kv_pos, front, back, -1)
+    qx, qpx = pad(q, 0, back), pad(q_pos, 0, back)
+    span = block + window - 1
+    first = P + front - (window - 1)        # keys of block 0 start here
+
+    def one(i):
+        at = i * block
+        sl = lambda t, start, size: jax.lax.dynamic_slice_in_dim(
+            t, start, size, axis=1)
+        return _masked_attention(
+            sl(qx, at, block), sl(kx, first + at, span),
+            sl(vx, first + at, span), sl(qpx, at, block),
+            sl(px, first + at, span), window)
+
+    if unroll or n == 1:
+        out = jnp.concatenate([one(i) for i in range(n)], axis=1)
+    else:                                   # (n, B, block, H, D)
+        out = jnp.moveaxis(jax.lax.map(one, jnp.arange(n)), 0, 1).reshape(
+            B, n * block, *q.shape[2:])
+    return out[:, :S]
+
+
+def _window_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                      cache: Optional[Params], cache_index, window: int,
+                      unroll: bool = False
+                      ) -> Tuple[jax.Array, Optional[Params]]:
+    """Sliding-window attention (a query at p sees keys p-window+1..p).
+
+    With a cache, the cache is a ring of Lr = min(window, max_len)
+    positions per sequence, position p at p % Lr: a step writes its last
+    min(S, Lr) keys there.  A one-token step attends over the ring itself;
+    a longer one over the ring as it was, in position order, followed by
+    its own keys, in blocks of half the window."""
+    B, S = q.shape[:2]
+    block = max(window // 2, 1)
+    if cache is None:
+        pos = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
+        return _band_attention(q, k, v, pos, pos, window, block,
+                               unroll), None
+    ring_k, ring_v = cache["k"], cache["v"]
+    Lr = ring_k.shape[1]
+    idx = jnp.broadcast_to(jnp.asarray(cache_index).reshape(-1), (B,))
+    q_pos = idx[:, None] + jnp.arange(S)[None]               # (B, S)
+    m = min(S, Lr)
+    rows = jnp.arange(B)[:, None]
+    cols = q_pos[:, S - m:] % Lr
+    new = {"k": ring_k.at[rows, cols].set(k[:, S - m:].astype(ring_k.dtype)),
+           "v": ring_v.at[rows, cols].set(v[:, S - m:].astype(ring_v.dtype))}
+    j = jnp.arange(Lr)[None]
+    if S == 1:
+        # the latest position of each ring entry, up to this step's own
+        last = idx[:, None]
+        kv_pos = last - (last - j) % Lr
+        return _masked_attention(q, new["k"], new["v"], q_pos, kv_pos,
+                                 window), new
+    old = idx[:, None] - Lr + j                               # (B, Lr)
+    take = lambda t: jnp.take_along_axis(
+        t, (old % Lr)[:, :, None, None], axis=1)
+    keys = jnp.concatenate([take(ring_k), k.astype(ring_k.dtype)], axis=1)
+    vals = jnp.concatenate([take(ring_v), v.astype(ring_v.dtype)], axis=1)
+    kv_pos = jnp.concatenate([old, q_pos], axis=1)
+    return _band_attention(q, keys, vals, q_pos, kv_pos, window, block,
+                           unroll), new
+
+
 def attention(p: Params, x: jax.Array, *, n_heads: int, n_kv: int,
               head_dim: int, positions: jax.Array, causal: bool,
               rope_theta: float, qk_norm: bool, norm_eps: float,
@@ -233,13 +373,17 @@ def attention(p: Params, x: jax.Array, *, n_heads: int, n_kv: int,
               decode_kv_splits: int = 1,
               unroll: bool = False,
               causal_block_skip: bool = False,
+              window: int = 0,
+              yarn: Optional[YaRN] = None,
               ) -> Tuple[jax.Array, Optional[Params]]:
     """GQA attention block body (no residual / pre-norm — caller owns those).
 
     cache: {'k': (B, L_max, G, D), 'v': ...} decode KV cache; cache_index is
     the number of tokens already in it.  memory: encoder output for
     cross-attention (whisper decoder) — keys/values come from memory instead
-    of x, no cache, no causal mask.
+    of x, no cache, no causal mask.  window > 0: sliding-window causal
+    self-attention, whose cache is a ring of ``window`` positions
+    (``_window_attention``).  yarn: RoPE with YaRN scaling.
     """
     from repro.parallel import sharding as shd
     B, S, _ = x.shape
@@ -273,9 +417,15 @@ def attention(p: Params, x: jax.Array, *, n_heads: int, n_kv: int,
         k = rms_norm(k, p["k_norm"], norm_eps)
 
     if memory is None:
-        q = apply_rope(q, positions, rope_theta)
+        q = apply_rope(q, positions, rope_theta, yarn)
         kv_positions = positions
-        k = apply_rope(k, kv_positions, rope_theta)
+        k = apply_rope(k, kv_positions, rope_theta, yarn)
+
+    if window:
+        out, new_cache = _window_attention(q, k, v, cache, cache_index,
+                                           window, unroll=unroll)
+        return _out_proj(p, out, decode=cache is not None and S == 1), \
+            new_cache
 
     new_cache = None
     kv_len = None
@@ -328,16 +478,23 @@ def attention(p: Params, x: jax.Array, *, n_heads: int, n_kv: int,
         out = _chunked_attention(q, k, v, causal=causal and memory is None,
                                  q_start=q_start, kv_len=kv_len,
                                  chunk=attn_chunk, unroll=unroll)
-    out = out.reshape(B, S, n_heads * head_dim)
-    if cache is not None and S == 1:
+    return _out_proj(p, out, decode=cache is not None and S == 1), new_cache
+
+
+def _out_proj(p: Params, out: jax.Array, decode: bool) -> jax.Array:
+    """wo over the heads' outputs (B, S, H, D)."""
+    from repro.parallel import sharding as shd
+    B, S = out.shape[:2]
+    out = out.reshape(B, S, -1)
+    if decode:
         # decode: wo's contraction dim (H*hd) is 'model'-sharded — pin the
         # attention output to match so wo is consumed in place (psum) rather
         # than gathered, and pin wo's OUTPUT D-sharded over 'data' likewise
         # (see ModelConfig.decode_replicate_acts)
         out = shd.constrain(out, "none", "none", "model")
         proj = dispatch.matmul2(out, p["wo"])
-        return shd.constrain(proj, "none", "none", "fsdp"), new_cache
-    return dispatch.matmul2(out, p["wo"]), new_cache
+        return shd.constrain(proj, "none", "none", "fsdp")
+    return dispatch.matmul2(out, p["wo"])
 
 
 # ---------------------------------------------------------------------------

@@ -30,7 +30,8 @@ from . import ssm as SSM
 
 Params = Dict[str, Any]
 
-ATTN, MAMBA = "attn", "mamba"
+ATTN, ATTN_WINDOW, MAMBA = "attn", "attn_window", "mamba"
+ATTN_KINDS = (ATTN, ATTN_WINDOW)     # full causal, sliding-window causal
 DENSE, MOE_MLP, MOE_DENSE, NONE = "dense", "moe", "moe+dense", "none"
 
 
@@ -50,6 +51,8 @@ class ModelConfig:
     head_dim: int = 0                      # 0 => d_model // n_heads
     # pattern: ((mixer, mlp), ...) repeated n_layers // len(pattern) times
     pattern: Tuple[Tuple[str, str], ...] = ((ATTN, DENSE),)
+    # sliding-window layers (mixer ATTN_WINDOW) see this many positions back
+    sliding_window: int = 0
     # MoE
     n_experts: int = 0
     top_k: int = 0
@@ -67,6 +70,7 @@ class ModelConfig:
     # numerics / structure
     qk_norm: bool = False
     rope_theta: float = 1e4
+    rope_yarn: Optional[L.YaRN] = None     # YaRN scaling of full layers' RoPE
     norm_eps: float = 1e-5
     dtype: Any = jnp.bfloat16
     remat: bool = True
@@ -141,7 +145,7 @@ class ModelConfig:
         out = []
         for mixer, mlp_kind in self.pattern:
             n = 2 * d                                        # norms
-            if mixer == ATTN:
+            if mixer in ATTN_KINDS:
                 n += self._attn_params()
             else:
                 di = 2 * d
@@ -165,7 +169,7 @@ def _init_layer(cfg: ModelConfig, key: jax.Array, mixer: str, mlp_kind: str,
     ks = jax.random.split(key, 6)
     p: Params = {"norm1": jnp.ones((cfg.d_model,), cfg.dtype),
                  "norm2": jnp.ones((cfg.d_model,), cfg.dtype)}
-    if mixer == ATTN:
+    if mixer in ATTN_KINDS:
         p["attn"] = L.init_attention(ks[0], cfg.d_model, cfg.n_heads,
                                      cfg.n_kv, cfg.hd, cfg.qk_norm, cfg.dtype)
     else:
@@ -190,6 +194,9 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
                               cfg.dtype),
         "final_norm": jnp.ones((cfg.d_model,), cfg.dtype),
     }
+    if not cfg.tie_embeddings:
+        params["lm_head"] = L.embed_init(keys[-2], cfg.padded_vocab,
+                                         cfg.d_model, cfg.dtype)
     # decoder stack: per pattern position, stacked over repeats
     layer_tree: Params = {}
     for i, (mixer, mlp_kind) in enumerate(cfg.pattern):
@@ -216,9 +223,16 @@ def _apply_layer(cfg: ModelConfig, p: Params, x: jax.Array, *,
                  mixer: str, mlp_kind: str, positions: jax.Array,
                  causal: bool, memory: Optional[jax.Array],
                  cache: Optional[Params], cache_index,
-                 ) -> Tuple[jax.Array, Optional[Params], jax.Array]:
-    """Pre-norm residual block.  Returns (x, new_cache, aux_loss)."""
+                 expert_layer: Optional[jax.Array] = None,
+                 ) -> Tuple[jax.Array, Optional[Params], jax.Array,
+                            Optional[jax.Array]]:
+    """Pre-norm residual block.  Returns (x, new_cache, aux_loss, rows):
+    rows (E,) int32 are the token copies routed to each expert where the
+    served MoE path ran, else None.  ``expert_layer``: the served path's
+    expert weights are the whole stack, and this is the layer's index in
+    it (``moe_dropless``)."""
     aux = jnp.zeros((), jnp.float32)
+    rows = None
     new_cache: Optional[Params] = dict(cache) if cache is not None else None
 
     if cache is not None and cfg.decode_replicate_acts:
@@ -240,15 +254,18 @@ def _apply_layer(cfg: ModelConfig, p: Params, x: jax.Array, *,
         return t
 
     h = _decode_fsdp(L.rms_norm(x, p["norm1"], cfg.norm_eps))
-    if mixer == ATTN:
+    if mixer in ATTN_KINDS:
         attn_cache = cache.get("attn") if cache is not None else None
+        windowed = mixer == ATTN_WINDOW
         out, nc = L.attention(
             p["attn"], h, n_heads=cfg.n_heads, n_kv=cfg.n_kv, head_dim=cfg.hd,
             positions=positions, causal=causal, rope_theta=cfg.rope_theta,
             qk_norm=cfg.qk_norm, norm_eps=cfg.norm_eps, cache=attn_cache,
             cache_index=cache_index, attn_chunk=cfg.attn_chunk,
             decode_kv_splits=cfg.decode_kv_splits, unroll=cfg.unroll_scan,
-            causal_block_skip=cfg.causal_block_skip)
+            causal_block_skip=cfg.causal_block_skip,
+            window=cfg.sliding_window if windowed else 0,
+            yarn=None if windowed else cfg.rope_yarn)
         if new_cache is not None:
             new_cache["attn"] = nc
     else:
@@ -276,14 +293,20 @@ def _apply_layer(cfg: ModelConfig, p: Params, x: jax.Array, *,
         if mlp_kind in (DENSE, MOE_DENSE):
             out = out + L.mlp(p["mlp"], h, tp=cfg.mlp_tp)
         if mlp_kind in (MOE_MLP, MOE_DENSE):
-            if cache is not None and h.shape[1] == 1:
+            from repro.parallel import sharding as shd
+            mesh = shd.active_mesh()
+            if cache is not None and mesh is None:
+                # served, one device: dropless grouped GEMMs
+                mo, rows = MOE.moe_dropless(p["moe"], h,
+                                            n_experts=cfg.n_experts,
+                                            top_k=cfg.top_k,
+                                            layer=expert_layer)
+            elif cache is not None and h.shape[1] == 1:
                 # decode only: the dense-all-experts path is O(T*E) — right
                 # for one token per sequence, catastrophic for a 32k prefill
                 mo = MOE.moe_decode(p["moe"], h, n_experts=cfg.n_experts,
                                     top_k=cfg.top_k)
             else:
-                from repro.parallel import sharding as shd
-                mesh = shd.active_mesh()
                 ep_ok = (mesh is not None and "model" in mesh.axis_names
                          and cfg.n_experts % mesh.shape["model"] == 0)
                 if ep_ok:
@@ -309,32 +332,62 @@ def _apply_layer(cfg: ModelConfig, p: Params, x: jax.Array, *,
                                       capacity_factor=cfg.capacity_factor)
             out = out + mo
         x = constrain(x + out, "batch", "seq", "none")
-    return x, new_cache, aux
+    return x, new_cache, aux, rows
 
 
 def _run_stack(cfg: ModelConfig, stack: Params, x: jax.Array, *,
                pattern: Tuple[Tuple[str, str], ...], positions: jax.Array,
                causal: bool, memory: Optional[jax.Array] = None,
                cache: Optional[Params] = None, cache_index=None,
-               ) -> Tuple[jax.Array, Optional[Params], jax.Array]:
+               ) -> Tuple[jax.Array, Optional[Params], jax.Array,
+                          Optional[jax.Array]]:
     """Scan over stacked repeats; pattern positions applied sequentially
-    inside the body.  cache (if given) is scanned alongside the params."""
+    inside the body.  cache (if given) is scanned alongside the params.
+    Returns (x, new_cache, aux, rows): rows (layers, E) int32, in layer
+    order, where the served MoE path ran, else None."""
+    from repro.parallel import sharding as shd
+    n_rep = jax.tree_util.tree_leaves(stack)[0].shape[0]
+    # the served MoE path (a cache, one device) reads each layer's experts
+    # from the whole stack in place: they leave the scanned slices, and the
+    # repeat index goes in instead
+    experts: Dict[str, Params] = {}
+    if cache is not None and shd.active_mesh() is None:
+        for i, (_, mlp_kind) in enumerate(pattern):
+            if mlp_kind in (MOE_MLP, MOE_DENSE):
+                moe = stack[f"pos{i}"]["moe"]
+                experts[f"pos{i}"] = {n: moe[n] for n in
+                                      ("w_gate", "w_up", "w_down")}
+    if experts:
+        stack = {pos: ({**lp, "moe": {"router": lp["moe"]["router"]}}
+                       if pos in experts else lp)
+                 for pos, lp in stack.items()}
+        xs = (stack, cache, jnp.arange(n_rep))
+    else:
+        xs = (stack, cache)
 
     def body(carry, scanned):
         x, aux = carry
-        layer_p, layer_c = scanned
+        layer_p, layer_c = scanned[:2]
         new_c: Dict[str, Any] = {}
+        rows = []
         for i, (mixer, mlp_kind) in enumerate(pattern):
             c_i = layer_c.get(f"pos{i}") if layer_c is not None else None
-            x, nc, a = _apply_layer(
-                cfg, layer_p[f"pos{i}"],
+            p_i, rep = layer_p[f"pos{i}"], None
+            if f"pos{i}" in experts:
+                p_i = {**p_i, "moe": {**p_i["moe"], **experts[f"pos{i}"]}}
+                rep = scanned[2]
+            x, nc, a, r = _apply_layer(
+                cfg, p_i,
                 x, mixer=mixer, mlp_kind=mlp_kind, positions=positions,
                 causal=causal, memory=memory, cache=c_i,
-                cache_index=cache_index)
+                cache_index=cache_index, expert_layer=rep)
             if nc is not None:
                 new_c[f"pos{i}"] = nc
+            if r is not None:
+                rows.append(r)
             aux = aux + a
-        return (x, aux), (new_c if new_c else None)
+        return (x, aux), (new_c if new_c else None,
+                          jnp.stack(rows) if rows else None)
 
     if cfg.remat:
         body = jax.checkpoint(body)
@@ -342,20 +395,20 @@ def _run_stack(cfg: ModelConfig, stack: Params, x: jax.Array, *,
     aux0 = jnp.zeros((), jnp.float32)
     if cfg.unroll_scan:
         # cost-accounting mode: true Python unroll (see ModelConfig)
-        n_rep = jax.tree_util.tree_leaves(stack)[0].shape[0]
         carry = (x, aux0)
-        caches = []
+        ys = []
         for r in range(n_rep):
-            sl = jax.tree_util.tree_map(lambda v: v[r], (stack, cache))
+            sl = jax.tree_util.tree_map(lambda v: v[r], xs)
             carry, y = body(carry, sl)
-            caches.append(y)
+            ys.append(y)
         (x, aux) = carry
-        new_cache = (jax.tree_util.tree_map(
-            lambda *vs: jnp.stack(vs), *caches) if caches[0] is not None
-            else None)
-        return x, new_cache, aux
-    (x, aux), new_cache = jax.lax.scan(body, (x, aux0), (stack, cache))
-    return x, new_cache, aux
+        stacked = jax.tree_util.tree_map(lambda *vs: jnp.stack(vs), *ys)
+    else:
+        (x, aux), stacked = jax.lax.scan(body, (x, aux0), xs)
+    new_cache, rows = stacked
+    if rows is not None:                # (repeats, positions, E)
+        rows = rows.reshape(-1, rows.shape[-1])
+    return x, new_cache, aux, rows
 
 
 # ---------------------------------------------------------------------------
@@ -404,8 +457,13 @@ def _chunked_xent(cfg: ModelConfig, x: jax.Array, embed: jax.Array,
     return loss_sum / denom, correct / denom
 
 
+def _head(cfg: ModelConfig, params: Params) -> jax.Array:
+    """The output head's (V, D) table: the embedding's where tied."""
+    return params["embed" if cfg.tie_embeddings else "lm_head"]
+
+
 def _logits(cfg: ModelConfig, params: Params, x: jax.Array) -> jax.Array:
-    return jnp.einsum("bsd,vd->bsv", x, params["embed"].astype(cfg.dtype)
+    return jnp.einsum("bsd,vd->bsv", x, _head(cfg, params).astype(cfg.dtype)
                       ).astype(jnp.float32)
 
 
@@ -435,9 +493,9 @@ def encode(cfg: ModelConfig, params: Params, frames: jax.Array) -> jax.Array:
     """Encoder stack over stub frame embeddings (B, L_enc, D)."""
     x = frames.astype(cfg.dtype)
     positions = jnp.arange(x.shape[1])
-    x, _, _ = _run_stack(cfg, {"pos0": params["encoder"]["pos0"]}, x,
-                         pattern=((ATTN, DENSE),), positions=positions,
-                         causal=False)
+    x, _, _, _ = _run_stack(cfg, {"pos0": params["encoder"]["pos0"]}, x,
+                            pattern=((ATTN, DENSE),), positions=positions,
+                            causal=False)
     # encoder params are stored under pos0 stacked; norm applied after
     return L.rms_norm(x, params["encoder"]["norm"], cfg.norm_eps)
 
@@ -450,8 +508,9 @@ def forward(params: Params, cfg: ModelConfig, batch: Dict[str, Any]
     memory = None
     if cfg.is_encdec:
         memory = encode(cfg, params, batch["encoder_embeds"])
-    x, _, aux = _run_stack(cfg, params["layers"], x, pattern=cfg.pattern,
-                           positions=positions, causal=True, memory=memory)
+    x, _, aux, _ = _run_stack(cfg, params["layers"], x, pattern=cfg.pattern,
+                              positions=positions, causal=True,
+                              memory=memory)
     x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
     return x, aux
 
@@ -465,7 +524,7 @@ def loss_fn(params: Params, cfg: ModelConfig, batch: Dict[str, Any],
     x_in = x[:, :-1]
     tgt = targets[:, 1:]
     msk = mask[:, 1:]
-    xent, acc = _chunked_xent(cfg, x_in, params["embed"], tgt, msk)
+    xent, acc = _chunked_xent(cfg, x_in, _head(cfg, params), tgt, msk)
     loss = xent + aux_weight * aux
     return loss, {"loss": loss, "xent": xent, "aux": aux, "acc": acc}
 
@@ -494,8 +553,11 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int) -> Params:
     """Decode cache pytree, stacked over repeats like the params."""
     cache: Params = {}
     for i, (mixer, _) in enumerate(cfg.pattern):
-        if mixer == ATTN:
-            kv = jnp.zeros((cfg.n_repeats, batch, max_len, cfg.n_kv, cfg.hd),
+        if mixer in ATTN_KINDS:
+            # a window layer keeps a ring of its window's positions
+            length = (min(cfg.sliding_window, max_len)
+                      if mixer == ATTN_WINDOW else max_len)
+            kv = jnp.zeros((cfg.n_repeats, batch, length, cfg.n_kv, cfg.hd),
                            cfg.dtype)
             cache[f"pos{i}"] = {"attn": {"k": kv, "v": kv}}
         else:
@@ -509,17 +571,19 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int) -> Params:
 
 def decode_step(params: Params, cfg: ModelConfig, tokens: jax.Array,
                 cache: Params, index: jax.Array,
-                memory: Optional[jax.Array] = None
-                ) -> Tuple[jax.Array, Params]:
+                memory: Optional[jax.Array] = None, *,
+                expert_rows: bool = False):
     """One decode step.  tokens (B, 1) int32; index = current length —
     scalar, or (B,) for per-slot positions (continuous batching).
-    Returns (logits (B, V), new cache)."""
+    Returns (logits (B, V), new cache), and with ``expert_rows`` the rows
+    routed to each expert of each served MoE layer, (layers, E) int32
+    (None for a model without one)."""
     x = _embed(cfg, params, tokens)
     positions = (jnp.asarray(index).reshape(-1, 1)
                  + jnp.arange(tokens.shape[1])[None, :])
     if cfg.is_encdec and memory is None:
         raise ValueError("enc-dec decode requires encoder memory")
-    x, new_cache, _ = _run_stack(
+    x, new_cache, _, rows = _run_stack(
         cfg, params["layers"], x, pattern=cfg.pattern, positions=positions,
         causal=True, memory=memory, cache=cache, cache_index=index)
     x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
@@ -527,22 +591,23 @@ def decode_step(params: Params, cfg: ModelConfig, tokens: jax.Array,
         # keep the D contraction aligned with the embed table's fsdp shard
         x = constrain(x, "none", "none", "fsdp")
     logits = _logits(cfg, params, x)[:, -1]
-    return logits, new_cache
+    return (logits, new_cache, rows) if expert_rows else (logits, new_cache)
 
 
 def prefill(params: Params, cfg: ModelConfig, batch: Dict[str, Any],
-            cache: Params) -> Tuple[jax.Array, Params]:
+            cache: Params, *, expert_rows: bool = False):
     """Prefill: run the full prompt through the stack, filling the cache.
-    Returns (last-position logits (B, V), cache)."""
+    Returns (last-position logits (B, V), cache), and with ``expert_rows``
+    the rows routed to each expert as ``decode_step`` gives them."""
     x, _, _ = _frontend_concat(cfg, params, batch)
     positions = jnp.arange(x.shape[1])
     memory = None
     if cfg.is_encdec:
         memory = encode(cfg, params, batch["encoder_embeds"])
-    x, new_cache, _ = _run_stack(
+    x, new_cache, _, rows = _run_stack(
         cfg, params["layers"], x, pattern=cfg.pattern, positions=positions,
         causal=True, memory=memory, cache=cache,
         cache_index=jnp.zeros((), jnp.int32))
     x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = _logits(cfg, params, x[:, -1:])[:, -1]
-    return logits, new_cache
+    return (logits, new_cache, rows) if expert_rows else (logits, new_cache)

@@ -1,9 +1,14 @@
 """Mixture-of-Experts layer (dbrx / arctic / jamba).
 
-Three execution paths, chosen by the layer wrapper:
+Four execution paths, chosen by the layer wrapper:
 
-  moe()        — single-device / no-mesh reference path (smoke tests): the
-                 argsort+scatter capacity dispatch, pure jnp.
+  moe_dropless() — served path (prefill and decode, no mesh): every token
+                 reaches its top-k experts, with no capacity.  The token
+                 copies are sorted by expert and run as grouped GEMMs whose
+                 rows per expert are only known at run time
+                 (``dispatch.grouped_matmul``), then unsorted and combined.
+  moe()        — training path without a mesh: the argsort+scatter
+                 capacity dispatch, pure jnp.
   moe_ep()     — production expert-parallel path via shard_map: experts are
                  sharded over the 'model' mesh axis; activations arrive
                  batch-sharded and model-replicated, so dispatch is a purely
@@ -14,7 +19,7 @@ Three execution paths, chosen by the layer wrapper:
                  jax-native mapping of the GShard/Switch all-to-all pattern
                  (DESIGN.md §6): GSPMD cannot shard a data-dependent scatter
                  on its own, so the EP structure is made explicit.
-  moe_decode() — decode path (few tokens): every expert runs on every token
+  moe_decode() — decode path under a mesh: every expert runs on every token
                  (dense einsum over the expert axis, EP-sharded by GSPMD) and
                  a sparse (T, E) weight matrix combines — no gathers of
                  expert weight slabs, which would defeat EP sharding.
@@ -27,11 +32,13 @@ targets (DESIGN.md §5).
 from __future__ import annotations
 
 import math
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
+
+from repro.kernels import dispatch
 
 from .layers import Params, dense_init
 
@@ -274,3 +281,39 @@ def moe_decode(p: Params, x: jax.Array, *, n_experts: int, top_k: int
         jnp.arange(T)[:, None], idx].add(w)                 # sparse combine
     out = jnp.einsum("etd,te->td", y.astype(jnp.float32), we)
     return out.reshape(B, S, D).astype(x.dtype)
+
+
+def moe_dropless(p: Params, x: jax.Array, *, n_experts: int, top_k: int,
+                 layer: Optional[jax.Array] = None
+                 ) -> Tuple[jax.Array, jax.Array]:
+    """Served path: x (B, S, D) -> (out, rows (E,) int32), ``rows[e]`` the
+    token copies routed to expert e.  Routing is f32 softmax over all E
+    experts, top-k, renormalised; no token is dropped.
+
+    With ``layer``, the expert weights are every layer's, stacked
+    (layers, E, ...), and this layer's are read where they lie: the stack
+    is one group list of layers x E groups, all empty but this layer's
+    (a slice of the stack would be copied for each grouped GEMM)."""
+    B, S, D = x.shape
+    T, k = B * S, top_k
+    xt = x.reshape(T, D)
+    logits = jnp.dot(xt.astype(jnp.float32), p["router"])
+    w, idx = _route(logits, k)                              # (T, k)
+    flat = idx.reshape(T * k)
+    order = jnp.argsort(flat, stable=True)                  # copies by expert
+    rows = jnp.bincount(flat, length=n_experts).astype(jnp.int32)
+    wg, wu, wd = p["w_gate"], p["w_up"], p["w_down"]
+    groups = rows
+    if layer is not None:
+        wg, wu, wd = (t.reshape(-1, *t.shape[2:]) for t in (wg, wu, wd))
+        groups = jax.lax.dynamic_update_slice(
+            jnp.zeros((wg.shape[0],), jnp.int32), rows, (layer * n_experts,))
+    xs = xt[order // k]                                     # (T*k, D)
+    g = dispatch.grouped_matmul(xs, wg, groups)
+    u = dispatch.grouped_matmul(xs, wu, groups)
+    y = dispatch.grouped_matmul(jax.nn.silu(g) * u, wd, groups)
+    back = jnp.zeros((T * k,), jnp.int32).at[order].set(
+        jnp.arange(T * k, dtype=jnp.int32))                 # inverse of order
+    y = y[back].reshape(T, k, D).astype(jnp.float32)
+    out = jnp.einsum("tkd,tk->td", y, w)
+    return out.reshape(B, S, D).astype(x.dtype), rows

@@ -26,7 +26,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.backend import HBM_GBPS, PEAK_BF16_TFLOPS, PEAK_FP32_TFLOPS
-from repro.models import ModelConfig, decode_step, init_cache, prefill
+from repro.models import (MOE_DENSE, MOE_MLP, ModelConfig, decode_step,
+                          init_cache, prefill)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -379,6 +380,20 @@ def _span(tr, name: str, **attrs):
     return _NULL_CTX if tr is None else tr.span(name, **attrs)
 
 
+def _expert_attrs(span, rows) -> None:
+    """A sampled span's routing counters, from the step's rows per expert
+    (layers, E): ``moe_rows`` (routed rows over all layers),
+    ``experts_touched`` (mean over layers of experts given a row) and
+    ``expert_rows_max`` (the largest group).  Fetched only here, so an
+    unsampled step never waits for them."""
+    if span is None or rows is None:
+        return
+    r = np.asarray(rows)
+    span.set(moe_rows=int(r.sum()),
+             experts_touched=float((r > 0).sum(-1).mean()),
+             expert_rows_max=int(r.max()))
+
+
 @dataclasses.dataclass
 class Request:
     """One request and its stamps, all on ``time.perf_counter`` (the span
@@ -537,8 +552,13 @@ class Engine:
         self._rng = jax.random.PRNGKey(serve_cfg.seed)
         self.ticks = 0
 
+        # a sparse-expert model's steps also return the rows routed to each
+        # expert, (layers, E), which a sampled span reads
+        self._moe = any(m in (MOE_MLP, MOE_DENSE) for _, m in cfg.pattern)
+        moe = self._moe
         self._decode = jax.jit(
-            lambda p, t, c, i: decode_step(p, cfg, t, c, i))
+            lambda p, t, c, i: decode_step(p, cfg, t, c, i,
+                                           expert_rows=moe))
         self._prefill_fns: Dict[int, Callable] = {}
         # jit tick telemetry: dispatch records at TRACE time only, so the
         # engine captures which kernel shapes each compiled program executes
@@ -743,24 +763,28 @@ class Engine:
         return self.controller.maybe_retune(tick=self.ticks)
 
     # -- prefill ---------------------------------------------------------------
-    def _prefill_one(self, slot: int, req: Request) -> None:
+    def _prefill_one(self, slot: int, req: Request):
+        """Prefill ``req`` into ``slot``; returns the rows routed to each
+        expert, (layers, E) on the device, or None without experts."""
         from repro.tunedb.telemetry import get_telemetry
 
-        cfg, sc = self.cfg, self.sc
+        cfg, sc, moe = self.cfg, self.sc, self._moe
         n = len(req.prompt)
         tokens = jnp.asarray(req.prompt[None])
         if n not in self._prefill_fns:
             def fn(params, tokens):
                 single = init_cache(cfg, 1, sc.max_len)
-                return prefill(params, cfg, {"tokens": tokens}, single)
+                return prefill(params, cfg, {"tokens": tokens}, single,
+                               expert_rows=moe)
             self._prefill_fns[n] = jax.jit(fn)
             # compiling call: capture the kernel shapes this prompt length
             # traces (the census count doubles as this execution's tick)
             with get_telemetry().capture() as cap:
-                logits, single = self._prefill_fns[n](self.params, tokens)
+                logits, single, *rows = self._prefill_fns[n](self.params,
+                                                             tokens)
             self._prefill_shapes[n] = cap.shapes
         else:
-            logits, single = self._prefill_fns[n](self.params, tokens)
+            logits, single, *rows = self._prefill_fns[n](self.params, tokens)
             if self._prefill_shapes.get(n):
                 get_telemetry().record_ticks(self._prefill_shapes[n])
 
@@ -774,6 +798,7 @@ class Engine:
         tok = int(self._sample(np.asarray(logits)[:, : cfg.vocab])[0])
         req.out.append(tok)
         req.token_times.append(time.perf_counter())
+        return rows[0] if rows else None
 
     def _sample(self, logits: np.ndarray) -> np.ndarray:
         if self.sc.temperature <= 0:
@@ -859,8 +884,9 @@ class Engine:
                     # compiled: this length's program is traced (and
                     # compiled or loaded from the compile cache) right here
                     with _span(tr, "engine.prefill", prompt_len=n,
-                               compiled=n not in self._prefill_fns):
-                        self._prefill_one(slot, req)
+                               compiled=n not in self._prefill_fns) as sp:
+                        rows = self._prefill_one(slot, req)
+                        _expert_attrs(sp, rows)
                     if root is not None:
                         root.set(
                             queue_wait_s=req.admitted_at - req.arrived_at,
@@ -870,15 +896,16 @@ class Engine:
                 break
 
             with (tr.root("engine.tick", tick=self.ticks, active=active)
-                  if tr is not None else _NULL_CTX):
-                active -= self._decode_tick(tr)
+                  if tr is not None else _NULL_CTX) as root:
+                active -= self._decode_tick(tr, root)
         return [r.out for r in queue]
 
-    def _decode_tick(self, tr) -> int:
+    def _decode_tick(self, tr, root=None) -> int:
         """One decode tick for every slot, in five phases (each a child
         span when traced); returns how many requests it retired.  Idle
         slots run on garbage that is discarded — static shapes, zero
-        recompiles."""
+        recompiles.  ``root``, the sampled tick span, gets the routing
+        counters of a sparse-expert model."""
         from repro.tunedb.telemetry import get_telemetry
 
         sc = self.sc
@@ -890,11 +917,11 @@ class Engine:
             if self._decode_shapes is None:
                 # compiling tick: the trace-time census IS this tick's count
                 with get_telemetry().capture() as cap:
-                    logits, self.cache = self._decode(
+                    logits, self.cache, *rows = self._decode(
                         self.params, jnp.asarray(last), self.cache, idx)
                 self._decode_shapes = cap.shapes
             else:
-                logits, self.cache = self._decode(
+                logits, self.cache, *rows = self._decode(
                     self.params, jnp.asarray(last), self.cache, idx)
                 if self._decode_shapes:
                     get_telemetry().record_ticks(self._decode_shapes)
@@ -904,6 +931,7 @@ class Engine:
             host = np.asarray(logits)
         with _span(tr, "engine.tick.sample"):
             toks = self._sample(host[:, : self.cfg.vocab])
+        _expert_attrs(root, rows[0] if rows else None)
         # the tick's one clock read: every token of this tick reached the
         # host by now, and the deadline is judged against it
         now = time.perf_counter()

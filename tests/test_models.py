@@ -54,7 +54,8 @@ def test_smoke_forward_and_train_step(arch):
 
 
 @pytest.mark.parametrize("arch", ["glm4-9b", "jamba-v0.1-52b",
-                                  "mamba2-1.3b", "dbrx-132b"])
+                                  "mamba2-1.3b", "dbrx-132b",
+                                  "mellum2-12b-a2.5b"])
 def test_smoke_decode_matches_forward(arch):
     """prefill + decode == teacher-forced forward, per family."""
     import dataclasses
@@ -67,8 +68,8 @@ def test_smoke_decode_matches_forward(arch):
     toks = jnp.asarray(np.random.default_rng(0).integers(
         0, cfg.vocab, (2, 12)), jnp.int32)
     x, _ = forward(params, cfg, {"tokens": toks})
-    logits_fwd = jnp.einsum("bsd,vd->bsv", x, params["embed"]
-                            )[..., : cfg.vocab]
+    head = params["embed" if cfg.tie_embeddings else "lm_head"]
+    logits_fwd = jnp.einsum("bsd,vd->bsv", x, head)[..., : cfg.vocab]
     cache = init_cache(cfg, 2, 16)
     lg, cache = prefill(params, cfg, {"tokens": toks[:, :8]}, cache)
     np.testing.assert_allclose(np.asarray(lg[:, :cfg.vocab]),
@@ -143,4 +144,4 @@ def test_applicability_rules():
     for a in ARCH_NAMES:
         assert {"train_4k", "prefill_32k", "decode_32k"} <= set(runnable[a])
     total = sum(len(v) for v in runnable.values())
-    assert total == 32          # 40 cells - 8 rule-skipped
+    assert total == 35          # 44 cells - 9 rule-skipped

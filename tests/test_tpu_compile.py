@@ -50,6 +50,18 @@ def _gemm(M, N, K, cfg=None):
             [(M, K), (K, N)])
 
 
+def _gmm(M, N, K, experts=64, layers=2):
+    """The grouped GEMM as the served MoE path calls it: two layers'
+    experts stacked, the second's groups holding the M rows evenly, with
+    the shape rule's tiles."""
+    G = experts * layers
+    sizes = jnp.zeros((G,), jnp.int32).at[G - experts:].set(M // experts)
+    tiles = space.gmm_default_tiles(M, N, K)
+    return ((lambda a, b: ops.grouped_matmul(a, b, sizes, tiles,
+                                             interpret=False)),
+            [(M, K), (G, K, N)])
+
+
 def _attention(Lq, Lkv, q_offset, cfg=None):
     B, Hq, Hkv, D = 1, 9, 3, 64                  # smollm-135m's heads
     return ((lambda q, k, v: ops.flash_attention(
@@ -89,6 +101,11 @@ CASES = {
     "gemm_4096_largest_legal": lambda: _gemm(4096, 4096, 4096, _largest(
         space.GEMM_SPACE, space.gemm_input(4096, 4096, 4096),
         lambda c, i: space.gemm_vmem_bytes(c, i["dtype_bits"]))),
+    # mellum2-12b-a2.5b's expert GEMMs: 4096 prompt tokens x top-8 through
+    # w_gate and w_down, and 16 decode slots x top-8 through w_gate
+    "gmm_mellum_prefill_gate": lambda: _gmm(32768, 896, 2304),
+    "gmm_mellum_prefill_down": lambda: _gmm(32768, 2304, 896),
+    "gmm_mellum_decode_gate": lambda: _gmm(128, 896, 2304),
     "attention_prefill": lambda: _attention(256, 256, 0),
     "attention_decode": lambda: _attention(1, 256, 100),
     "attention_prefill_largest_legal": lambda: _attention(256, 256, 0, _largest(
